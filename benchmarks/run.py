@@ -29,6 +29,7 @@ from benchmarks import (
     table1_quant_accuracy,
 )
 from repro.kernels import probe
+from repro.runtime.compile_cache import configure_compile_cache
 
 MODULES = [
     ("table1+2 (quant accuracy)", table1_quant_accuracy),
@@ -60,6 +61,7 @@ def main(argv=None) -> None:
              "trajectory format",
     )
     args = ap.parse_args(argv)
+    configure_compile_cache()
     modules = MODULES
     if args.only:
         keys = [k.strip().lower() for k in args.only.split(",") if k.strip()]

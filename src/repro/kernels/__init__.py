@@ -3,14 +3,7 @@
 - quant_matmul.py: INT8/packed-INT4 MXU matmul (the reconfigurable PE array)
 - two_stage_attention.py: paper Alg. 1 (stats pass + recompute pass)
 - wht.py: multiplier-free blocked Walsh-Hadamard butterfly
+- fused.py: the unified datapath (prologue + int matmul + epilogue, one-launch FFN)
 Each has a jitted wrapper in ops.py and a pure-jnp oracle in ref.py;
 validated in interpret mode on CPU, lowered by Mosaic on TPU.
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-
-def tpu_compiler_params(**kw):
-    """Compat shim: ``pltpu.TPUCompilerParams`` was renamed to
-    ``pltpu.CompilerParams`` across JAX releases; accept either."""
-    cls = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
-    return cls(**kw)

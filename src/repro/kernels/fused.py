@@ -40,14 +40,19 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.versaq import _act_fn as _act_rows
-from repro.kernels import tpu_compiler_params
-from repro.kernels.quant_matmul import _sign_extend4
+from repro.kernels.quant_matmul import unpack_int4_planes
 
 __all__ = ["fused_matmul", "fused_ffn", "norm_quant"]
 
 LANE = 128
+UNPACK_ROWS = 256  # packed weight rows unpacked per step of _int_dot
+# Scoped VMEM the fused kernels may claim: their weight panels stay
+# resident, which outgrows the compiler's 16 MiB default at VGGT widths
+# (a v5e core has 128 MiB).
+VMEM_LIMIT = 64 * 1024 * 1024
 
 
 # ---------------------------------------------------------------------------
@@ -96,12 +101,24 @@ def _wht_rows(x, h, block: int):
     return xv.reshape(r, d)
 
 
-def _idct_rows(y, d, block: int):
-    """Online block IDCT ŷ·D (cancels the offline ·Dᵀ weight transform)."""
-    r, n = y.shape
-    y = y.reshape(r, n // block, block)
-    y = jnp.einsum("rkb,bc->rkc", y, d)
-    return y.reshape(r, n)
+def _idct_rows(y, d):
+    """Online block IDCT ŷ·D (cancels the offline ·Dᵀ weight transform).
+
+    ``d`` is the block-diagonal IDCT over one lane slice (``I₂ ⊗ D₆₄`` on
+    128 lanes, see :func:`_lane_dct`), applied slice by slice: folding the
+    64-wide blocks into rows instead is a relayout Mosaic refuses."""
+    w = d.shape[0]
+    return jnp.concatenate(
+        [jnp.dot(y[:, c : c + w], d) for c in range(0, y.shape[1], w)], axis=-1
+    )
+
+
+def _lane_dct(dct, dct_block: int, *widths: int):
+    """The [blk, blk] DCT as a block-diagonal matrix over one 128-lane
+    slice, when every width it applies to is lane-aligned; else as is."""
+    if LANE % dct_block or any(w % LANE for w in widths):
+        return dct.astype(jnp.float32)
+    return jnp.kron(jnp.eye(LANE // dct_block, dtype=jnp.float32), dct.astype(jnp.float32))
 
 
 def _quant_rows(x, bits: int):
@@ -114,24 +131,30 @@ def _quant_rows(x, bits: int):
     return q.astype(jnp.int8), scale
 
 
-def _int_dot(xv, w, packed: bool):
-    """int8 [r, K] × (int8 [K, N] | packed uint8 [K/2, N]) -> int32 [r, N].
+def _int_dot(xv, w_ref, packed: bool):
+    """int8 [r, K] × (int8 [K, N] | packed uint8 [K/2, N] ref) -> int32 [r, N].
 
     Packed layout: original K rows [0, K/2) in low nibbles, [K/2, K) in
     high nibbles — the two nibble planes contract against contiguous
-    column halves of the activation, no in-kernel deinterleave.
+    column halves of the activation, no in-kernel deinterleave.  The
+    panel is unpacked ``UNPACK_ROWS`` packed rows at a time, so the int32
+    unpack temporaries stay a slice of the panel, not four times it.
     """
     dn = (((1,), (0,)), ((), ()))
-    if packed:
-        kp = w.shape[0]
-        wlo = _sign_extend4(w & 0xF)
-        whi = _sign_extend4(w >> 4)
-        return jax.lax.dot_general(
-            xv[:, :kp], wlo, dn, preferred_element_type=jnp.int32
+    if not packed:
+        return jax.lax.dot_general(xv, w_ref[...], dn, preferred_element_type=jnp.int32)
+    kp = w_ref.shape[0]
+    acc = None
+    for c in range(0, kp, UNPACK_ROWS):
+        c1 = min(c + UNPACK_ROWS, kp)
+        wlo, whi = unpack_int4_planes(w_ref[c:c1, :])
+        part = jax.lax.dot_general(
+            xv[:, c:c1], wlo, dn, preferred_element_type=jnp.int32
         ) + jax.lax.dot_general(
-            xv[:, kp:], whi, dn, preferred_element_type=jnp.int32
+            xv[:, kp + c : kp + c1], whi, dn, preferred_element_type=jnp.int32
         )
-    return jax.lax.dot_general(xv, w, dn, preferred_element_type=jnp.int32)
+        acc = part if acc is None else acc + part
+    return acc
 
 
 # ---------------------------------------------------------------------------
@@ -205,7 +228,9 @@ def norm_quant(
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT
+        ),
     )(*operands)
 
 
@@ -227,10 +252,10 @@ def _fused_matmul_kernel(*refs, names, cfg):
         if cfg["pro_wht_block"] is not None:
             x = _wht_rows(x, r["h_pro"][...], cfg["pro_wht_block"])
         xv, xs = _quant_rows(x, cfg["a_bits"])
-    acc = _int_dot(xv, r["wv"][...], cfg["packed"])
+    acc = _int_dot(xv, r["wv"], cfg["packed"])
     y = acc.astype(jnp.float32) * xs * r["ws"][...]
     if cfg["dct_block"] is not None:
-        y = _idct_rows(y, r["dct"][...], cfg["dct_block"])
+        y = _idct_rows(y, r["dct"][...])
     if "bias" in r:
         y = y + r["bias"][...]
     y = _act_rows(y, cfg["act"])
@@ -323,8 +348,9 @@ def fused_matmul(
     ]
     if dct_block is not None:
         assert dct is not None
+        dct = _lane_dct(dct, dct_block, n)
         names.append("dct")
-        operands.append(dct.astype(jnp.float32))
+        operands.append(dct)
         in_specs.append(pl.BlockSpec(dct.shape, lambda i: (0, 0)))
     if bias is not None:
         names.append("bias")
@@ -364,7 +390,9 @@ def fused_matmul(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT
+        ),
     )(*operands)
 
 
@@ -384,9 +412,9 @@ def _fused_ffn_kernel(*refs, names, cfg):
     xv, xs = _quant_rows(x, cfg["a_bits_in"])
 
     def proj(wn, sn, bn, packed, idct):
-        y = _int_dot(xv, r[wn][...], packed).astype(jnp.float32) * xs * r[sn][...]
+        y = _int_dot(xv, r[wn], packed).astype(jnp.float32) * xs * r[sn][...]
         if idct:
-            y = _idct_rows(y, r["dct"][...], cfg["dct_block"])
+            y = _idct_rows(y, r["dct"][...])
         if bn in r:
             y = y + r[bn][...]
         return y
@@ -400,10 +428,10 @@ def _fused_ffn_kernel(*refs, names, cfg):
     if cfg["mid_wht_block"] is not None:
         h = _wht_rows(h, r["h_mid"][...], cfg["mid_wht_block"])
     hq, hs = _quant_rows(h, cfg["a_bits_mid"])
-    y = _int_dot(hq, r["wd"][...], cfg["packed_d"]).astype(jnp.float32)
+    y = _int_dot(hq, r["wd"], cfg["packed_d"]).astype(jnp.float32)
     y = y * hs * r["wds"][...]
     if cfg["idct_out"]:
-        y = _idct_rows(y, r["dct"][...], cfg["dct_block"])
+        y = _idct_rows(y, r["dct"][...])
     if "bd" in r:
         y = y + r["bd"][...]
     r["out"][...] = y.astype(r["out"].dtype)
@@ -501,7 +529,7 @@ def fused_ffn(
         const("bd", bd.reshape(1, n_out).astype(jnp.float32))
     if idct_h or idct_out:
         assert dct is not None and dct_block is not None
-        const("dct", dct.astype(jnp.float32))
+        const("dct", _lane_dct(dct, dct_block, dff, n_out))
     cfg = dict(
         gated=gated, packed_g=packed_g, packed_u=packed_u, packed_d=packed_d,
         a_bits_in=a_bits_in, a_bits_mid=a_bits_mid, norm_kind=norm_kind,
@@ -516,5 +544,7 @@ def fused_ffn(
         out_specs=pl.BlockSpec((bm, n_out), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n_out), out_dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT
+        ),
     )(*operands)

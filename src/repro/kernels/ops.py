@@ -50,7 +50,15 @@ LANE = 8  # sublane granularity the TPU lowerings want tiles aligned to
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU backend (tests), Mosaic on TPU.  Any other
+    backend is an error: interpreting there would hide the missing chip."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels lower for TPU only (interpret mode on CPU); "
+            f"backend {backend!r} has neither"
+        )
+    return backend == "cpu"
 
 
 def quant_linear_matmul(

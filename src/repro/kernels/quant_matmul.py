@@ -28,16 +28,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 DEFAULT_BM = 256
 DEFAULT_BN = 256
 DEFAULT_BK = 512
 
 
-def _sign_extend4(x: jnp.ndarray) -> jnp.ndarray:
-    x = x.astype(jnp.int8)
-    return jnp.where(x > 7, x - 16, x)
+def unpack_int4_planes(wp: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Packed uint8 [r, N] -> sign-extended (low, high) int8 nibble planes.
+
+    Computed in int32: Mosaic has no int8 vector compare and no uint8
+    shift on TPU.  ``(v ^ 8) - 8`` sign-extends a 4-bit value without a
+    compare, and ``w >> 4`` of a byte is already its high nibble.
+    """
+    w = wp.astype(jnp.int32)
+    lo = ((w & 0xF) ^ 8) - 8
+    hi = ((w >> 4) ^ 8) - 8
+    return lo.astype(jnp.int8), hi.astype(jnp.int8)
 
 
 def _w8_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *, nk):
@@ -68,9 +74,7 @@ def _w4_kernel(xlo_ref, xhi_ref, wp_ref, xs_ref, ws_ref, o_ref, acc_ref, *, nk):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    wp = wp_ref[...]
-    wlo = _sign_extend4(wp & 0xF)
-    whi = _sign_extend4(wp >> 4)
+    wlo, whi = unpack_int4_planes(wp_ref[...])
     dn = (((1,), (0,)), ((), ()))
     acc_ref[...] += jax.lax.dot_general(
         xlo_ref[...], wlo, dn, preferred_element_type=jnp.int32
@@ -156,7 +160,7 @@ def quant_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(*operands)

@@ -35,8 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 NEG_INF = -1e30
 
 T_Q = 64
@@ -232,7 +230,7 @@ def two_stage_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(qv, kv, qs, ks)
@@ -263,7 +261,7 @@ def two_stage_attention(
         out_shape=jax.ShapeDtypeStruct((bh, lq, dh), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(qv, kv, vv, qs, ks, m, l)

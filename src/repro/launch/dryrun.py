@@ -206,7 +206,12 @@ def main() -> None:
                     if args.attn:
                         cmd += ["--attn", args.attn]
                     print(">>", " ".join(cmd), flush=True)
-                    r = subprocess.run(cmd, timeout=args.timeout)
+                    # the cell runs on fake host devices; the child never
+                    # takes a chip, which belongs to one process at a time
+                    r = subprocess.run(
+                        cmd, timeout=args.timeout,
+                        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                    )
                     if r.returncode != 0:
                         failures.append(name)
         if failures:

@@ -1,19 +1,23 @@
 """Production mesh construction (single-pod 16x16, multi-pod 2x16x16).
 
+Axes are ``Auto``: the sharding rules leave layout to the compiler.
 A FUNCTION, not a module constant — importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before any jax import).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over available devices (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh(
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

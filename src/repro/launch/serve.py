@@ -31,8 +31,16 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core.versaq import QuantPolicy
 from repro.data.pipeline import mixed_len_prompts, scene_batch
+from repro.runtime.compile_cache import configure_compile_cache
 from repro.serving.engine import Engine
 from repro.serving.server import AsyncServer
+
+# How long the launcher waits for one request.  The first request of a
+# shape includes its compile, and requests queue behind each other: one
+# 32-frame VGGT-1B scene at W4A8 takes about 111 s on a TPU v5e chip, so
+# the fourth of four waits about 8 minutes.
+RESULT_TIMEOUT_S = 3600
+
 
 def _parse_policy(s: str, method: str):
     """Thin wrapper over :class:`repro.launch.specs.ServeSpec` — one
@@ -107,7 +115,7 @@ def _collect(srv: AsyncServer, reqs: list) -> list:
         if r is None:  # rejected at submit (QueueFull under --admission reject)
             continue
         try:
-            outs.append(srv.result(r, timeout=600))
+            outs.append(srv.result(r, timeout=RESULT_TIMEOUT_S))
         except ServeError as e:
             print(f"request {i}: {type(e).__name__}: {e}")
     return outs
@@ -141,7 +149,7 @@ def _server(eng, args) -> AsyncServer:
     return srv
 
 
-def serve_vggt(cfg, args) -> None:
+def serve_vggt(cfg, args) -> tuple[int, int]:
     from repro.models import vggt
     from repro.serving.vggt_engine import VGGTEngine
 
@@ -167,17 +175,16 @@ def serve_vggt(cfg, args) -> None:
             for r in range(args.requests)
         ]
         outs = _collect(srv, reqs)
-    if not outs:
-        print(f"served 0/{len(reqs)} requests")
-        print(eng.stats.format())
-        return
-    out = outs[-1]
-    print(f"served {len(outs)}/{len(reqs)} requests -> poses{tuple(out['pose'].shape)} "
-          f"points{tuple(out['points'].shape)}")
+    shapes = ""
+    if outs:
+        shapes = (f" -> poses{tuple(outs[-1]['pose'].shape)} "
+                  f"points{tuple(outs[-1]['points'].shape)}")
+    print(f"served {len(outs)}/{len(reqs)} requests{shapes}")
     print(eng.stats.format())
+    return len(outs), len(reqs)
 
 
-def serve_lm(cfg, args) -> None:
+def serve_lm(cfg, args) -> tuple[int, int]:
     from repro.models import lm
 
     key = jax.random.PRNGKey(0)
@@ -212,9 +219,22 @@ def serve_lm(cfg, args) -> None:
           f"decode {eng.stats.decode_s*1e3:.1f}ms  "
           f"({eng.stats.decode_tokens_per_s:.0f} decode tok/s)")
     print(eng.stats.format())
+    return len(outs), len(reqs)
+
+
+def _failures_expected(args) -> bool:
+    """Flags under which a failed request is an intended outcome: chaos
+    faults, admission bounds that reject or shed, and request deadlines."""
+    return (
+        args.faults is not None
+        or args.max_pending is not None
+        or args.max_queued_tokens is not None
+        or args.deadline_s is not None
+    )
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b-smoke")
     ap.add_argument("--policy", default="w4a8",
@@ -275,10 +295,9 @@ def main():
     args = ap.parse_args()
 
     cfg = get_config(args.arch)
-    if cfg.vggt:
-        serve_vggt(cfg, args)
-    else:
-        serve_lm(cfg, args)
+    served, total = (serve_vggt if cfg.vggt else serve_lm)(cfg, args)
+    if served < total and not _failures_expected(args):
+        raise SystemExit(f"{total - served} of {total} requests failed")
 
 
 if __name__ == "__main__":

@@ -11,7 +11,7 @@ import jax
 from repro.configs import get_config
 from repro.launch import specs, roofline_util as ru
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_config("qwen3-14b-smoke").with_(d_model=256, n_heads=8, n_kv_heads=2, head_dim=32, d_ff=512)
 
 import dataclasses

@@ -78,3 +78,26 @@ def test_tiers_malformed_raises(bad):
 
     with pytest.raises(ValueError):
         _tiers(_targs(bad), None, None)
+
+
+@pytest.mark.parametrize("flags,fails", [
+    ([], True),
+    (["--faults", "nan@scene:req=0"], False),
+    (["--max-pending", "2"], False),
+    (["--deadline-s", "1"], False),
+])
+def test_failed_request_exits_nonzero_unless_expected(monkeypatch, flags, fails):
+    """A request that fails makes the launcher exit non-zero, except under
+    the flags whose point is to make requests fail."""
+    import sys
+
+    from repro.launch import serve
+
+    monkeypatch.setattr(serve, "configure_compile_cache", lambda: None)
+    monkeypatch.setattr(serve, "serve_vggt", lambda cfg, args: (3, 4))
+    monkeypatch.setattr(sys, "argv", ["serve", "--arch", "vggt-1b-smoke", *flags])
+    if fails:
+        with pytest.raises(SystemExit, match="1 of 4 requests failed"):
+            serve.main()
+    else:
+        serve.main()

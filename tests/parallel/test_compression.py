@@ -7,7 +7,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 from repro.parallel.compression import compressed_psum
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 g_all = jnp.asarray(rng.normal(size=(8, 1000)), jnp.float32)
 
@@ -61,7 +61,7 @@ params = lm.init_params(cfg, key)
 opt_cfg = adamw.AdamWConfig(lr=5e-3, warmup_steps=3, total_steps=25)
 dc = DataConfig(vocab_size=32, batch=8, seq_len=16)
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 step_c = make_ddp_compressed_step(cfg, opt_cfg, mesh)
 opt = adamw.init(params)
 err = compression.init_error_state(params)

@@ -7,7 +7,7 @@ PIPE = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.parallel.pipeline import pipeline_apply
 
-mesh = jax.make_mesh((4,), ("pipe",))
+mesh = jax.make_mesh((4,), ("pipe",), axis_types=(jax.sharding.AxisType.Auto,))
 S, B, D = 4, 8, 16
 key = jax.random.PRNGKey(0)
 w = jax.random.normal(key, (S, D, D)) * 0.3

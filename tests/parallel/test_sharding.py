@@ -28,7 +28,7 @@ step = make_train_step(cfg, adamw.AdamWConfig(lr=1e-3))
 p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
 # 2x4 mesh DP x TP
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 with mesh:
     pspec = sharding.make_param_pspecs(params)
     psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspec, is_leaf=lambda x: isinstance(x, P))
@@ -69,7 +69,7 @@ qp = quantize_lm(cfg, lm.init_params(cfg, key), W4A8)
 toks = jax.random.randint(key, (4, 8), 0, cfg.vocab_size)
 ref, _ = jax.jit(lambda p, t: lm.forward(cfg, p, t))(qp, toks)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 with mesh:
     pspec = sharding.make_param_pspecs(qp)
     psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspec, is_leaf=lambda x: isinstance(x, P))

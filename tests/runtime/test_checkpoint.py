@@ -117,14 +117,14 @@ from repro.checkpoint.manager import CheckpointManager
 import sys
 
 d = sys.argv[1] if len(sys.argv) > 1 else "/tmp/elastic_ckpt"
-mesh8 = jax.make_mesh((2, 4), ("data", "model"))
+mesh8 = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 x = jnp.arange(64.0).reshape(8, 8)
 xs = jax.device_put(x, NamedSharding(mesh8, P("data", "model")))
 mgr = CheckpointManager(d)
 mgr.save(1, {"x": xs})
 
 # reload onto a DIFFERENT mesh shape (elastic restart)
-mesh4 = jax.make_mesh((4, 2), ("data", "model"))
+mesh4 = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 template = {"x": jax.device_put(jnp.zeros((8, 8)), NamedSharding(mesh4, P("model", "data")))}
 got, _, _ = mgr.restore(template)
 np.testing.assert_array_equal(np.asarray(got["x"]), np.asarray(x))
