@@ -9,7 +9,7 @@ telemetry stack on and consume every surface a production scrape would
 3. Scrape ``/metrics`` (Prometheus text), ``/stats`` (summary JSON) and
    ``/trace?request=`` (one request's span chain) over real HTTP.
 4. Tail the JSONL trace file and print the per-request chains plus the
-   quant-health and kernel-launch counters the registry collected.
+   quant-health callback and sample counters the registry collected.
 
 Run:  PYTHONPATH=src python examples/observe_serving.py [--requests 4]
 """
@@ -73,14 +73,14 @@ def main():
         metrics_text = _get(addr, "/metrics")
         wanted = [
             "serve_admitted_total", "serve_bucket_calls_total",
-            "serve_request_latency_seconds_bucket", "kernel_launches_total",
+            "serve_request_latency_seconds_bucket", "quant_health_callbacks_total",
             "quant_clip_rate", "quant_health_samples_total",
         ]
         present = [n for n in wanted if n in metrics_text]
         print(f"scraped /metrics: {len(metrics_text.splitlines())} lines, "
               f"families present: {present}")
         for line in metrics_text.splitlines():
-            if line.startswith(("kernel_launches_total{", "quant_clip_rate{")):
+            if line.startswith(("quant_health_callbacks_total{", "quant_clip_rate{")):
                 print(f"  {line}")
 
         # ---- /stats: the unified engine summary ------------------------

@@ -295,6 +295,7 @@ def two_stage_mha(
     causal: bool = False,
     a_bits: int = 8,
     interpret: bool | None = None,
+    role: str | None = None,
     **tile_kw,
 ) -> jnp.ndarray:
     """Paper-Alg.-1 attention over float [B, H, L, dh] inputs.
@@ -309,6 +310,7 @@ def two_stage_mha(
     the paper's T_Q/T_K/T_V, padding Lq (garbage rows sliced off) and Lk
     (tail keys masked in-kernel via ``kv_len``) when no healthy divisor
     exists.  Explicitly passed tiles must divide exactly (legacy behavior).
+    ``role`` names the two launches (``two_stage_attention``'s ``role``).
     """
     interpret = _default_interpret() if interpret is None else interpret
     b, h, lq, dh = q.shape
@@ -349,6 +351,7 @@ def two_stage_mha(
         q_heads=h if hkv != h else None,
         kv_heads=hkv if hkv != h else None,
         kv_len=lk if lkp != lk else None,
+        role=role,
         **tile_kw,
     )
     return out[:, :lq].reshape(b, h, lq, dh)

@@ -140,11 +140,15 @@ def _stage2_kernel(
         ).astype(o_ref.dtype)
 
 
+def _launch_name(role: str | None, stage: str) -> str | None:
+    return None if role is None else f"two_stage_attention_{role}_{stage}"
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
         "causal", "scale", "bq", "bk", "bkv", "out_dtype", "interpret",
-        "q_heads", "kv_heads", "kv_len",
+        "q_heads", "kv_heads", "kv_len", "role",
     ),
 )
 def two_stage_attention(
@@ -165,6 +169,7 @@ def two_stage_attention(
     q_heads: int | None = None,
     kv_heads: int | None = None,
     kv_len: int | None = None,
+    role: str | None = None,
 ) -> jnp.ndarray:
     """Two-stage INT8 attention over [BH, L, dh] int8 tensors.
 
@@ -179,6 +184,12 @@ def two_stage_attention(
 
     **kv_len**: real key count when L was lane-padded; the kernel masks
     the tail columns out of both stages' softmax.
+
+    **role**: a caller's static label (VGGT's ``frame`` / ``global``
+    blocks).  The two launches are then named
+    ``two_stage_attention_<role>_stats`` (stage ①) and
+    ``two_stage_attention_<role>_out`` (stage ②) in compiled HLO and in
+    device traces; without one both keep this function's name.
     """
     bh, lq, dh = qv.shape
     lk = kv.shape[1]
@@ -233,6 +244,7 @@ def two_stage_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name=_launch_name(role, "stats"),
     )(qv, kv, qs, ks)
 
     # Stage ②: recompute with mega-tiles, final stats as inputs
@@ -264,6 +276,7 @@ def two_stage_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name=_launch_name(role, "out"),
     )(qv, kv, vv, qs, ks, m, l)
     return (out * v_scale).astype(out_dtype)
 

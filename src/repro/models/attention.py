@@ -244,7 +244,8 @@ def sdpa_dispatch(cfg, q, k, v, *, causal: bool, q_offset=0, kv_len=None, kv_mas
                           compute_dtype=getattr(cfg, "attn_dtype", "f32"), kv_mask=kv_mask)
 
 
-def _two_stage_kernel_sdpa(q, k, v, *, causal: bool, tiles: tuple | None = None):
+def _two_stage_kernel_sdpa(q, k, v, *, causal: bool, tiles: tuple | None = None,
+                           role: str | None = None):
     """Quantized fast path: the paper's INT8 two-stage Pallas kernel.
 
     q: [B,Lq,H,dh]; k/v: [B,Lk,Hkv,dh] float (already per-head rotated by
@@ -266,6 +267,7 @@ def _two_stage_kernel_sdpa(q, k, v, *, causal: bool, tiles: tuple | None = None)
         jnp.moveaxis(k, 2, 1),
         jnp.moveaxis(v, 2, 1),
         causal=causal,
+        role=role,
         **(dict(tiles) if tiles else {}),
     )
     return jnp.moveaxis(o, 1, 2)
@@ -282,7 +284,10 @@ def gqa_attention(
     mode: str = "full",
     kv_mask: Optional[jnp.ndarray] = None,
     pad_lens: Optional[jnp.ndarray] = None,
+    role: Optional[str] = None,
 ) -> tuple[jnp.ndarray, Optional[KVCache]]:
+    """``role`` is a static label for the two-stage kernel's launch names
+    (``kernels/two_stage_attention.py``); other paths ignore it."""
     b, lq, _ = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     if "wqkv" in p:
@@ -336,7 +341,7 @@ def gqa_attention(
             # kv_mask and any L.
             o = _two_stage_kernel_sdpa(
                 q, k, v, causal=causal,
-                tiles=getattr(cfg, "attn_tiles", None),
+                tiles=getattr(cfg, "attn_tiles", None), role=role,
             )
         if o is None:
             o = sdpa_dispatch(cfg, q, k, v, causal=causal, kv_mask=kv_mask)

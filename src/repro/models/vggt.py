@@ -78,10 +78,13 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32) -> dict:
     return params
 
 
-def _block(p, cfg: ModelConfig, x: jnp.ndarray, kv_mask=None) -> jnp.ndarray:
+def _block(p, cfg: ModelConfig, x: jnp.ndarray, role: str, kv_mask=None) -> jnp.ndarray:
+    """One AA block; ``role`` (``frame`` / ``global``) names its attention
+    kernel's launches in compiled HLO and device traces."""
     # fused sites absorb their pre-norm (unified-datapath prologue)
     h = x if F.carries_norm(p["attn"]) else L.norm(p["attn_norm"], x)
-    out, _ = A.gqa_attention(p["attn"], cfg, h, causal=False, mode="full", kv_mask=kv_mask)
+    out, _ = A.gqa_attention(p["attn"], cfg, h, causal=False, mode="full", kv_mask=kv_mask,
+                             role=role)
     x = x + out * p["ls1"].astype(out.dtype) if "ls1" in p else x + out
     h = x if F.carries_norm(p["ffn"]) else L.norm(p["ffn_norm"], x)
     out = F.dense_ffn(p["ffn"], cfg.act, h)
@@ -151,13 +154,16 @@ def forward(
 
     def group_body(carry, gp):
         xc = carry  # [B, S, T, d]
-        # frame-wise attention
+        # frame-wise attention (each block's ops carry its role in their
+        # op_name metadata)
         xf = xc.reshape(b * s, t, d)
-        xf = _block(gp["frame"], cfg, xf, kv_mask=fmask)
+        with jax.named_scope("frame"):
+            xf = _block(gp["frame"], cfg, xf, "frame", kv_mask=fmask)
         xc = xf.reshape(b, s, t, d)
         # global attention over all frames' tokens
         xg = xc.reshape(b, s * t, d)
-        xg = _block(gp["global"], cfg, xg, kv_mask=gmask)
+        with jax.named_scope("global"):
+            xg = _block(gp["global"], cfg, xg, "global", kv_mask=gmask)
         xc = xg.reshape(b, s, t, d)
         if act_sharding is not None:
             xc = jax.lax.with_sharding_constraint(xc, act_sharding)

@@ -13,16 +13,15 @@ Three pillars, each usable alone:
 
 `enable_all()` flips everything on for a serving process (AsyncServer
 calls it when started with a metrics port); `disable_all()` restores the
-zero-overhead default.  The kernel probe's global counters are bridged
-into the registry by a render-time collector, so `/metrics` always shows
-current launch totals without the probe knowing about Prometheus.
+zero-overhead default.  The kernel probe's launch counts are not part of
+live telemetry: they are recorded when a graph is traced, not when it
+runs, so on a serving process they stop moving after warm-up.
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
-from repro.kernels import probe
 from repro.obs import metrics, quant_health, trace
 
 __all__ = [
@@ -32,16 +31,7 @@ __all__ = [
     "enable_all",
     "disable_all",
     "enabled",
-    "kernel_counter_collector",
 ]
-
-
-def kernel_counter_collector(registry: metrics.Registry) -> None:
-    """Render-time collector: mirror the probe's global counters into the
-    registry (no-op until `probe.enable_global()` has run)."""
-    g = probe.global_counters()
-    if g is not None:
-        metrics.export_kernel_counters(registry, g.counts, g.nbytes)
 
 
 _enabled = False
@@ -57,8 +47,8 @@ def enable_all(
     trace_path: Optional[str] = None,
     quant_every: int = 64,
 ) -> trace.Tracer:
-    """Turn on live telemetry: inline metrics, span tracing, always-on
-    kernel counters, and sampled quant-health monitors.
+    """Turn on live telemetry: inline metrics, span tracing and sampled
+    quant-health monitors.
 
     Idempotent; a tracer already installed is kept unless `trace_path`
     asks for a JSONL mirror it doesn't have.  Returns the active tracer.
@@ -66,10 +56,7 @@ def enable_all(
     in forwards traced *after* this call.
     """
     global _enabled
-    reg = registry or metrics.default()
     metrics.set_live(True)
-    probe.enable_global()
-    reg.register_collector(kernel_counter_collector)
     quant_health.enable(every=quant_every, registry=registry)
     tr = trace.current()
     if tr is None or (trace_path is not None and tr.jsonl_path != trace_path):
@@ -79,16 +66,13 @@ def enable_all(
     return tr
 
 
-def disable_all(registry: Optional[metrics.Registry] = None) -> None:
+def disable_all() -> None:
     """Back to the zero-overhead default.  Leaves already-compiled graphs
     as they are (quant-health callbacks baked into a traced graph keep
     firing but drop their samples once disabled here)."""
     global _enabled
-    reg = registry or metrics.default()
     metrics.set_live(False)
     quant_health.disable()
-    probe.disable_global()
-    reg.unregister_collector(kernel_counter_collector)
     tr = trace.uninstall()
     if tr is not None:
         tr.close()

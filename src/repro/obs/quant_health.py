@@ -21,8 +21,13 @@ signals to `PrecisionPlan` site paths:
 Monitoring is OFF by default and costs nothing when off (`enabled()` is
 a dict lookup at trace time).  When on, `monitor()` adds a few cheap
 elementwise reductions to the traced graph and ships three scalars to
-the host via `jax.debug.callback`; the host side samples every
-`every`-th call per site before touching the metrics registry.
+the host via `jax.debug.callback`; the host side counts every arrival
+(`quant_health_callbacks_total`) and samples every `every`-th call per
+site before touching the rest of the metrics registry.  The host time
+spent inside the callbacks is summed process-wide (`host_seconds()`) and
+each callback runs under a `quant_health.observe` profiler annotation.
+A site inside a scanned stack of blocks carries one name for every
+layer of the scan.
 
 Note: enable *before* the forward is traced — jit caches compiled
 graphs, so a graph traced while monitoring was off never reports.
@@ -33,6 +38,7 @@ from __future__ import annotations
 
 import functools
 import threading
+import time
 from typing import Dict, Optional
 
 import jax
@@ -45,6 +51,7 @@ from repro.obs import metrics as obs_metrics
 _lock = threading.Lock()
 _cfg: Dict[str, object] = {"every": 0, "registry": None}
 _calls: Dict[str, int] = {}
+_host_s = 0.0  # seconds spent inside _observe, process-wide
 
 
 def enable(every: int = 16, registry: Optional[obs_metrics.Registry] = None) -> None:
@@ -73,19 +80,39 @@ def _registry() -> obs_metrics.Registry:
     return reg if isinstance(reg, obs_metrics.Registry) else obs_metrics.default()
 
 
+def host_seconds() -> float:
+    """Host seconds spent inside the callbacks since the process started
+    (every engine's); a caller reads it before and after a forward."""
+    with _lock:
+        return _host_s
+
+
 def _observe(site: str, a_bits: int, clip_frac, crest, overflow) -> None:
     """Host-side sink (runs under jax.debug.callback).  Values arrive as
     numpy scalars — or batched arrays under vmap — so reduce defensively."""
+    global _host_s
     every = _cfg["every"]
     if not every:
         return
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation("quant_health.observe"):
+        _record(site, a_bits, int(every), clip_frac, crest, overflow)  # type: ignore[arg-type]
+    with _lock:
+        _host_s += time.perf_counter() - t0
+
+
+def _record(site: str, a_bits: int, every: int, clip_frac, crest, overflow) -> None:
     with _lock:
         n = _calls.get(site, 0)
         _calls[site] = n + 1
-    if n % int(every):  # type: ignore[arg-type]
-        return
     reg = _registry()
     lbl = dict(site=site, a_bits=str(a_bits))
+    reg.counter(
+        "quant_health_callbacks_total", "Quant-health callbacks that reached the host",
+        ("site", "a_bits"),
+    ).inc(1.0, **lbl)
+    if n % every:
+        return
     reg.gauge(
         "quant_clip_rate", "Fraction of activations in the extreme quant bin", ("site", "a_bits")
     ).set(float(np.mean(clip_frac)), **lbl)

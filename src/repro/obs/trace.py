@@ -12,9 +12,14 @@ every event to a JSONL file for offline tooling.
 
 Timestamps: `t` is `time.perf_counter()` (monotonic — use for intra-
 process ordering and durations), `wall` is `time.time()` (epoch — use to
-line events up with external logs).  `span()` additionally wraps the
-body in `jax.named_scope` + `jax.profiler.TraceAnnotation` so device
-profiles carry the same phase names as the JSONL stream.
+line events up with external logs).  `start_ns` / `end_ns` are
+`time.time_ns()` at the phase's start and end (equal for a point event;
+where only `dur_s` is known, `start_ns` is `end_ns` less it):
+the realtime clock the JAX profiler stamps its host events with and maps
+device timestamps onto, so an event lines up with a profiler trace.
+`span()` additionally wraps the body in `jax.named_scope` +
+`jax.profiler.TraceAnnotation` so device profiles carry the same phase
+names as the JSONL stream.
 """
 
 from __future__ import annotations
@@ -41,12 +46,15 @@ class SpanEvent:
     phase: str
     t: float                      # monotonic seconds (time.perf_counter)
     wall: float                   # epoch seconds (time.time)
+    start_ns: int                 # epoch nanoseconds (time.time_ns), phase start
+    end_ns: int                   # epoch nanoseconds (time.time_ns), phase end
     request: Optional[str] = None
     dur_s: Optional[float] = None
     labels: Dict[str, Any] = field(default_factory=dict)
 
     def to_dict(self) -> dict:
-        d = {"phase": self.phase, "t": self.t, "wall": self.wall}
+        d = {"phase": self.phase, "t": self.t, "wall": self.wall,
+             "start_ns": self.start_ns, "end_ns": self.end_ns}
         if self.request is not None:
             d["request"] = self.request
         if self.dur_s is not None:
@@ -71,12 +79,21 @@ class Tracer:
         phase: str,
         request: Optional[str] = None,
         dur_s: Optional[float] = None,
+        start_ns: Optional[int] = None,
+        end_ns: Optional[int] = None,
         **labels: Any,
     ) -> SpanEvent:
+        """Record one event; ``end_ns`` defaults to now and ``start_ns``
+        to ``end_ns`` less ``dur_s`` (so a point event has the two equal)."""
+        end_ns = time.time_ns() if end_ns is None else end_ns
+        if start_ns is None:
+            start_ns = end_ns - round((dur_s or 0.0) * 1e9)
         ev = SpanEvent(
             phase=phase,
             t=time.perf_counter(),
             wall=time.time(),
+            start_ns=start_ns,
+            end_ns=end_ns,
             request=request,
             dur_s=dur_s,
             labels=labels,
@@ -151,14 +168,19 @@ def span(phase: str, request: Optional[str] = None, emit_event: bool = True, **l
 
     Wraps the body in `jax.named_scope` + `jax.profiler.TraceAnnotation`
     (so traced HLO and device timelines carry the phase name) and, unless
-    `emit_event=False`, emits one event with the measured wall duration.
+    `emit_event=False`, emits one event with the measured wall duration
+    and the annotation's `start_ns`/`end_ns`.  Yields the event's label
+    dict: the body may add labels it only learns while it runs.
     """
     tr = _tracer
     if tr is None:
-        yield
+        yield labels
         return
+    start_ns = time.time_ns()
     t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation(phase), jax.named_scope(phase):
-        yield
+        yield labels
+    dur_s = time.perf_counter() - t0
+    end_ns = time.time_ns()
     if emit_event:
-        tr.emit(phase, request=request, dur_s=time.perf_counter() - t0, **labels)
+        tr.emit(phase, request=request, dur_s=dur_s, start_ns=start_ns, end_ns=end_ns, **labels)

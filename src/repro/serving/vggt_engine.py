@@ -45,6 +45,7 @@ from repro.configs.base import ModelConfig
 from repro.core.model_quant import quantize_vggt
 from repro.core.versaq import QuantPolicy
 from repro.models import vggt as vggt_mod
+from repro.obs import quant_health
 from repro.obs import trace as obs_trace
 from repro.serving import batching, faults as faults_mod
 from repro.serving.batching import (
@@ -165,6 +166,7 @@ class VGGTEngine:
         self.pad_patches = pad_patches
         self.stats = VGGTServeStats()
         self._fns: dict[tuple, Any] = {}
+        self._forwards = 0  # forward ordinal, the batch-level span events' parent id
         # micro-batch queues, one per (frames, bucketed patches) group
         self._queue = batching.MicroBatchQueue(self._run, self.max_batch, max_wait_s)
         # robustness layer (docs/robustness.md): bounded admission,
@@ -367,55 +369,66 @@ class VGGTEngine:
     # ---- micro-batch execution -------------------------------------------
 
     def _run(self, key: tuple[str, int, int], reqs: list[PendingRequest]) -> None:
+        """Serve one micro-batch.  Besides the per-request ``admit`` /
+        ``forward`` events, each host phase around the forward is a span
+        (``vggt.assemble``, ``vggt.check``, ``vggt.deliver``) with one
+        batch-level event carrying this engine's ``forward`` ordinal, the
+        ``requests`` served and the ``bucket``."""
         tier, frames, p_bucket = key
         for r in reqs:
             obs_trace.emit(
                 "admit", request=r.req_id, tier=tier, frames=frames,
                 patches=p_bucket, mid_decode=False,
             )
-        params = self.tier_params(tier)
-        n_real = sum(r.scenes.shape[0] for r in reqs)
-        bucket = self.bucket_for(n_real, frames, p_bucket, tier)
-        d = reqs[0].scenes.shape[-1]
-        dtype = reqs[0].scenes.dtype
+        self._forwards += 1
+        batch = dict(forward=self._forwards, requests=[r.req_id for r in reqs])
+        with obs_trace.span("vggt.assemble", **batch) as labels:
+            params = self.tier_params(tier)
+            n_real = sum(r.scenes.shape[0] for r in reqs)
+            bucket = self.bucket_for(n_real, frames, p_bucket, tier)
+            labels["bucket"] = batch["bucket"] = str(bucket)
+            d = reqs[0].scenes.shape[-1]
+            dtype = reqs[0].scenes.dtype
 
-        # mask only when some request actually has padded patches: the
-        # mask-free graph is cheaper and keeps the quantized two_stage
-        # kernel fast path live (it requires kv_mask=None)
-        masked = any(r.n_patches < bucket.patches for r in reqs)
-        inj = self._injector
-        if inj is not None:
-            inj.sleep("prefill")  # the forward is VGGT's prefill stage
-        parts, mask_parts = [], []
-        for r in reqs:
-            x = r.scenes
+            # mask only when some request actually has padded patches: the
+            # mask-free graph is cheaper and keeps the quantized two_stage
+            # kernel fast path live (it requires kv_mask=None)
+            masked = any(r.n_patches < bucket.patches for r in reqs)
+            inj = self._injector
             if inj is not None:
-                v = inj.activation("scene", r.req_id)
-                if v is not None:  # poison one input element of this scene
-                    x = x.at[0, 0, 0, 0].add(v)
-            if x.shape[2] < bucket.patches:  # pad patch dim (masked)
-                pad = bucket.patches - x.shape[2]
-                x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            parts.append(x)
-            if masked:
-                m = jnp.zeros((x.shape[0], frames, bucket.patches), bool)
-                mask_parts.append(m.at[:, :, : r.n_patches].set(True))
-        if n_real < bucket.batch:  # pad batch dim with empty scenes
-            slack = bucket.batch - n_real
-            parts.append(jnp.zeros((slack, frames, bucket.patches, d), dtype))
-            if masked:
-                mask_parts.append(jnp.ones((slack, frames, bucket.patches), bool))
-        x = jnp.concatenate(parts, axis=0)
-        fn = self._bucket_fn(bucket, masked)
+                inj.sleep("prefill")  # the forward is VGGT's prefill stage
+            parts, mask_parts = [], []
+            for r in reqs:
+                x = r.scenes
+                if inj is not None:
+                    v = inj.activation("scene", r.req_id)
+                    if v is not None:  # poison one input element of this scene
+                        x = x.at[0, 0, 0, 0].add(v)
+                if x.shape[2] < bucket.patches:  # pad patch dim (masked)
+                    pad = bucket.patches - x.shape[2]
+                    x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                parts.append(x)
+                if masked:
+                    m = jnp.zeros((x.shape[0], frames, bucket.patches), bool)
+                    mask_parts.append(m.at[:, :, : r.n_patches].set(True))
+            if n_real < bucket.batch:  # pad batch dim with empty scenes
+                slack = bucket.batch - n_real
+                parts.append(jnp.zeros((slack, frames, bucket.patches, d), dtype))
+                if masked:
+                    mask_parts.append(jnp.ones((slack, frames, bucket.patches), bool))
+            x = jnp.concatenate(parts, axis=0)
+            args = (params, x, jnp.concatenate(mask_parts, axis=0)) if masked else (params, x)
+            fn = self._bucket_fn(bucket, masked)
 
+        qh0 = quant_health.host_seconds()
+        start_ns = time.time_ns()
         t0 = time.perf_counter()
         with obs_trace.span("forward", emit_event=False, bucket=str(bucket)):
-            if masked:
-                out = fn(params, x, jnp.concatenate(mask_parts, axis=0))
-            else:
-                out = fn(params, x)
+            out = fn(*args)
             jax.block_until_ready(out)
         dt = time.perf_counter() - t0
+        end_ns = time.time_ns()
+        qh_s = quant_health.host_seconds() - qh0
 
         bs = self.stats.bucket(bucket)
         bs.calls += 1
@@ -425,35 +438,38 @@ class VGGTEngine:
         bs.latencies_s.append(dt)
         for r in reqs:
             obs_trace.emit(
-                "forward", request=r.req_id, dur_s=dt, bucket=str(bucket),
-                tier=tier, scenes=r.scenes.shape[0],
+                "forward", request=r.req_id, dur_s=dt, start_ns=start_ns, end_ns=end_ns,
+                bucket=str(bucket), tier=tier, scenes=r.scenes.shape[0],
+                forward=batch["forward"], quant_health_s=qh_s,
             )
 
         # per-request finiteness over the real (unpadded) reconstruction
         # outputs, reduced on device and read in one host transfer — a
         # non-finite scene batch fails only its own request
-        oks, i0 = [], 0
-        for r in reqs:
-            b = r.scenes.shape[0]
-            ok = jnp.array(True)
-            for k in ("pose", "points", "depth", "conf"):
-                a = out[k][i0 : i0 + b]
-                if k != "pose":
-                    a = a[:, :, : r.n_patches]
-                ok = jnp.logical_and(ok, jnp.isfinite(a).all())
-            oks.append(ok)
-            i0 += b
-        okh = np.asarray(jnp.stack(oks))
+        with obs_trace.span("vggt.check", **batch):
+            oks, i0 = [], 0
+            for r in reqs:
+                b = r.scenes.shape[0]
+                ok = jnp.array(True)
+                for k in ("pose", "points", "depth", "conf"):
+                    a = out[k][i0 : i0 + b]
+                    if k != "pose":
+                        a = a[:, :, : r.n_patches]
+                    ok = jnp.logical_and(ok, jnp.isfinite(a).all())
+                oks.append(ok)
+                i0 += b
+            okh = np.asarray(jnp.stack(oks))
 
-        i0 = 0
-        ns = self.cfg.n_special_tokens
-        for idx, r in enumerate(reqs):
-            b = r.scenes.shape[0]
-            if okh[idx]:
-                r._deliver(_slice_result(out, i0, b, r.n_patches, ns))
-            else:
-                self._numeric_fault(r)
-            i0 += b
+        with obs_trace.span("vggt.deliver", **batch):
+            i0 = 0
+            ns = self.cfg.n_special_tokens
+            for idx, r in enumerate(reqs):
+                b = r.scenes.shape[0]
+                if okh[idx]:
+                    r._deliver(_slice_result(out, i0, b, r.n_patches, ns))
+                else:
+                    self._numeric_fault(r)
+                i0 += b
 
 
 def _slice_result(out: dict, i0: int, b: int, n_patches: int, ns: int) -> dict:

@@ -26,6 +26,7 @@ def test_observe_serving_example_smoke():
     )
     assert r.returncode == 0, f"example failed:\nSTDOUT:{r.stdout}\nSTDERR:{r.stderr}"
     assert "scraped /metrics" in r.stdout
-    assert "kernel_launches_total" in r.stdout
+    assert "quant_health_callbacks_total" in r.stdout
+    assert "kernel_launches_total" not in r.stdout
     assert "chain=enqueue -> admit -> prefill -> decode -> complete" in r.stdout
     assert "observability tour OK" in r.stdout

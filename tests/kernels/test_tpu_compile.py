@@ -12,6 +12,8 @@ the compiler accepts.
 Token counts are a VGGT-1B scene's global attention length:
 S frames × (1,369 patches + 5 special tokens), for S = 8 and 32.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -77,10 +79,12 @@ def _spec(one_chip, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-def _compile(fn, *args) -> None:
+def _compile(fn, *args) -> str:
     """Compile for the described chip; the program must hold the Mosaic
-    kernel."""
-    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+    kernel.  Returns the compiled HLO text."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
 
 
 @pytest.mark.parametrize(
@@ -151,3 +155,30 @@ def test_packed_w4_quant_matmul_compiles(one_chip, m):
         lambda x, w: ops.quant_linear_matmul(x, w, interpret=False),
         _spec(one_chip, (m, 1024)), wq,
     )
+
+
+def test_vggt_forward_names_attention_launches_by_role(one_chip, monkeypatch):
+    """A whole W4A8 VGGT-1B forward (one AA pair, two frames) compiled for
+    the chip: each block's two-stage launches carry its role and stage,
+    no launch keeps the bare ``two_stage_attention`` name, and the fused
+    kernels keep theirs."""
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = get_config("vggt-1b").with_(n_layers=1, attn_impl="two_stage")
+    plan = ServeSpec.parse("w4a8:fused").materialize()
+    tree = jax.eval_shape(
+        lambda: quantize_vggt(cfg, vggt.init_params(cfg, jax.random.PRNGKey(0)), plan)
+    )
+    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+    text = _compile(
+        lambda p, x: vggt.forward(cfg, p, x), params, _spec(one_chip, (1, 2, 1369, 1024))
+    )
+    launches = {
+        re.sub(r"\.\d+$", "", m)
+        for m in re.findall(r"%?([\w.]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    }
+    roles = {f"two_stage_attention_{r}_{s}" for r in ("frame", "global") for s in ("stats", "out")}
+    assert roles <= launches
+    assert "two_stage_attention" not in launches
+    assert {"fused_matmul", "fused_ffn"} <= launches
+    assert 'op_name="jit(<lambda>)/while/body/closed_call/frame/' in text
+    assert 'op_name="jit(<lambda>)/while/body/closed_call/global/' in text

@@ -193,6 +193,51 @@ def test_span_emits_duration_event():
         trace.install(prev)
 
 
+def test_span_event_lines_up_with_its_profiler_annotation(tmp_path):
+    """A span's `start_ns`/`end_ns` are on the profiler's clock: its
+    TraceAnnotation in the `/host:CPU` plane starts and ends within 1 ms
+    of them (plane times are offsets from the trace's start)."""
+    import glob
+    import os
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    prev = trace.install(trace.Tracer())
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace.span("vggt.assemble", forward=1):
+                time.sleep(0.005)
+        finally:
+            jax.profiler.stop_trace()
+        (ev,) = trace.current().recent()
+    finally:
+        trace.install(prev)
+    assert ev.end_ns - ev.start_ns >= 5_000_000
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    env = {k: v for p in pd.planes if p.name == "Task Environment" for k, v in p.stats}
+    base = env["profile_start_time"]
+    (ann,) = [e for p in pd.planes if p.name == "/host:CPU"
+              for line in p.lines for e in line.events if e.name == "vggt.assemble"]
+    assert abs(base + ann.start_ns - ev.start_ns) < 1e6
+    assert abs(base + ann.end_ns - ev.end_ns) < 1e6
+
+
+def test_event_start_and_end_ns():
+    tr = trace.Tracer()
+    ev = tr.emit("enqueue", request="r1")
+    assert ev.start_ns == ev.end_ns  # a point event
+    d = ev.to_dict()
+    assert d["start_ns"] == d["end_ns"] == ev.end_ns
+    ev = tr.emit("complete", request="r1", dur_s=0.25)
+    assert ev.end_ns - ev.start_ns == 250_000_000
+    ev = tr.emit("forward", request="r1", dur_s=0.1, start_ns=5, end_ns=7)
+    assert (ev.start_ns, ev.end_ns) == (5, 7)
+
+
 # ---------------------------------------------------------------------------
 # quant_health: host-side sampling
 # ---------------------------------------------------------------------------
@@ -214,6 +259,25 @@ def test_quant_health_observe_samples_every_nth():
         quant_health.disable()
     quant_health._observe("blk.wq", 8, 0.5, 1.0, 0)  # disabled: dropped
     assert quant_health.sites_sampled() == {}
+
+
+@pytest.mark.parametrize("every", [1, 4, 64])
+def test_quant_health_counts_every_callback_and_samples_every_nth(every):
+    reg = metrics.Registry()
+    quant_health.enable(every=every, registry=reg)
+    h0 = quant_health.host_seconds()
+    try:
+        for _ in range(130):
+            quant_health._observe("pair.global.attn.wqkv", 8, 0.1, 3.0, 0)
+        lbl = dict(site="pair.global.attn.wqkv", a_bits="8")
+        assert reg.get("quant_health_callbacks_total").value(**lbl) == 130
+        assert reg.get("quant_health_samples_total").value(**lbl) == -(-130 // every)
+        assert quant_health.host_seconds() > h0
+    finally:
+        quant_health.disable()
+    h1 = quant_health.host_seconds()
+    quant_health._observe("pair.global.attn.wqkv", 8, 0.1, 3.0, 0)  # disabled
+    assert quant_health.host_seconds() == h1
 
 
 def test_quant_health_enable_validates_every():
@@ -248,14 +312,15 @@ def test_enable_all_disable_all_round_trip():
         assert obs.enabled()
         assert metrics.live()
         assert quant_health.enabled()
-        assert probe.global_counters() is not None
+        # trace-time kernel counts are not live telemetry
+        assert probe.global_counters() is None
         assert trace.current() is tr
-        probe.record("some_kernel", 2, nbytes=64)
-        # the registry mirror of the probe globals is collector-driven
+        quant_health._observe("blk.wq", 8, 0.125, 2.0, 0)
         text = reg.render_prometheus()
-        assert 'kernel_launches_total{kernel="some_kernel"} 2' in text
+        assert 'quant_health_callbacks_total{site="blk.wq",a_bits="8"} 1' in text
+        assert "kernel_launches_total" not in text
     finally:
-        obs.disable_all(registry=reg)
+        obs.disable_all()
         if was_on:
             obs.enable_all()
     if not was_on:

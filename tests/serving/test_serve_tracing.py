@@ -97,6 +97,48 @@ def test_vggt_span_chain(tracer):
     assert evs["forward"].dur_s > 0
 
 
+def test_vggt_host_phases_are_spans_of_one_forward(tracer):
+    """One micro-batched forward leaves one batch-level event for each host
+    phase around it, all carrying the same forward ordinal and request
+    ids, each lying inside the admit → last complete interval; the
+    per-request chains stay as they were, and the per-request forward
+    events carry the ordinal and the quant-health callbacks' host time."""
+    cfg, params = _vggt_fixture()
+    from repro.data.pipeline import scene_batch
+
+    reg = obs_metrics.Registry()
+    quant_health.enable(every=1, registry=reg)
+    try:
+        eng = VGGTEngine(cfg, params, policy=W4A8, max_batch=2, max_wait_s=60.0)
+        reqs = [eng.enqueue(jax.numpy.asarray(scene_batch(1, 2, 8, cfg.d_model, i)["patches"]))
+                for i in range(2)]
+        assert all(r.ready for r in reqs)  # the second fills max_batch and flushes
+    finally:
+        quant_health.disable()
+    ids = [r.req_id for r in reqs]
+    for rid in ids:
+        assert tracer.phases(rid) == ["enqueue", "admit", "forward", "complete"]
+    evs = tracer.recent()
+    host = {e.phase: e for e in evs if e.phase.startswith("vggt.")}
+    assert sorted(host) == ["vggt.assemble", "vggt.check", "vggt.deliver"]
+    assert len([e for e in evs if e.phase.startswith("vggt.")]) == 3
+    fwd = [e for e in evs if e.phase == "forward"]
+    (ordinal,) = {e.labels["forward"] for e in fwd}
+    assert all(e.labels["quant_health_s"] > 0 for e in fwd)
+    admit0 = min(e.start_ns for e in evs if e.phase == "admit")
+    done = max(e.end_ns for e in evs if e.phase == "complete")
+    for e in host.values():
+        assert e.request is None
+        assert e.labels["forward"] == ordinal and e.labels["requests"] == ids
+        assert e.labels["bucket"] == fwd[0].labels["bucket"]
+        assert admit0 <= e.start_ns <= e.end_ns
+        assert e.dur_s * 1e9 <= e.end_ns - e.start_ns + 1e6
+    assert host["vggt.assemble"].end_ns <= fwd[0].start_ns
+    assert fwd[0].end_ns <= host["vggt.check"].start_ns
+    assert host["vggt.check"].end_ns <= host["vggt.deliver"].start_ns <= done
+    assert sum(e.dur_s for e in host.values()) + fwd[0].dur_s <= (done - admit0) * 1e-9
+
+
 def test_evicted_request_chain_ends_in_evicted(tracer):
     cfg, params = _lm_fixture()
     eng = Engine(cfg, params, max_len=32, mode="continuous", max_wait_s=0.0)
