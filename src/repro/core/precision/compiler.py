@@ -132,8 +132,9 @@ class FusedGroupSchedule:
 
 @dataclasses.dataclass(frozen=True)
 class AttentionSchedule:
-    """Two-stage attention tile targets (resolved via ``lane_tile`` at
-    trace time — sequence lengths are runtime-dependent)."""
+    """Two-stage attention tile targets (resolved via
+    ``kernels.ops.attention_tiles`` at trace time — sequence lengths are
+    runtime-dependent)."""
 
     impl: str
     tiles: tuple = ()

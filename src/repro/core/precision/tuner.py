@@ -53,9 +53,9 @@ _MATMUL_BM = (128, 256, 512)
 _MATMUL_BN = (128, 256, 512)
 _MATMUL_BK = (256, 512, 1024)
 _FUSED_BM = (128, 256, 512)
-_ATTN_BQ = (64, 128)
-_ATTN_BK = (64, 128)
-_ATTN_BKV = (1024, 2048)
+_ATTN_BQ = (128, 256, 512)
+_ATTN_BK = (256, 512, 1024, 2048, 4096)
+_ATTN_BKV = (1024, 2048, 4096)
 
 
 def matmul_key(

@@ -4,12 +4,14 @@ These are the entry points the rest of the framework uses.  On CPU (this
 container) they run in interpret mode for validation; on TPU they compile
 to Mosaic.  ``interpret`` defaults from the backend.
 
-Tiling policy (``lane_tile``): serving token counts (S·(n_special+P),
-prompt buckets, odd scene sizes) are rarely multiples of the paper's
-64/2048 tiles.  Exact divisor tiles are used when a lane-aligned one
-exists; otherwise the length is padded to the next lane multiple (masked
-or sliced off) instead of degrading to tile=1 kernels — a prime-sized dim
-used to lower a degenerate one-row-per-step grid.
+Tiling policy: serving token counts (S·(n_special+P), prompt buckets,
+odd scene sizes) are rarely multiples of a kernel's tiles.  The matmuls
+(``lane_tile``) use an exact divisor tile when an 8-aligned one exists;
+otherwise the length is padded to the next multiple of 8 (masked or sliced
+off) instead of degrading to tile=1 kernels — a prime-sized dim used to
+lower a degenerate one-row-per-step grid.  Attention
+(``attention_tiles``) pads its token axes to multiples of 128, the lanes a
+score tile's key axis lies on.
 
 Every wrapper records its kernel launches with ``kernels.probe`` so tests
 and benchmarks can assert Pallas-call counts (the fused datapath's whole
@@ -29,6 +31,7 @@ from repro.kernels import probe
 from repro.kernels import quant_matmul as _qm
 from repro.kernels import two_stage_attention as _tsa
 from repro.kernels import wht as _wht
+from repro.obs import trace as obs_trace
 
 __all__ = [
     "quant_linear_matmul",
@@ -47,6 +50,8 @@ __all__ = [
 ]
 
 LANE = 8  # sublane granularity the TPU lowerings want tiles aligned to
+ATTN_LANE = 128  # a score tile puts its key axis on the 128-lane vreg
+ATTN_PAD_FRAC = 0.03  # most padding that buys an attention axis a larger tile
 
 
 def _default_interpret() -> bool:
@@ -209,6 +214,36 @@ def matmul_tiles(
     return bm, mp, bn, bk
 
 
+def _attention_axis(length: int, target: int) -> int:
+    """Padded length of an attention token axis tiled toward ``target``.
+
+    A multiple of 128 (``ATTN_LANE``).  Where that length's best
+    128-aligned tile is under half the target (11,008 = 128·2·43 allows
+    only 256), a multiple of 512 or 1,024 instead, if it costs ≤ 3% of the
+    length.  An axis of at most 128 tokens is one tile, padded to 8.
+    """
+    if length <= ATTN_LANE:
+        return -(-length // LANE) * LANE
+    target = max(target, ATTN_LANE)
+    padded = -(-length // ATTN_LANE) * ATTN_LANE
+    best = _aligned_divisor(padded, target, ATTN_LANE)
+    for step in (512, 1024):
+        p = -(-length // step) * step
+        if best < target // 2 and p - length <= ATTN_PAD_FRAC * length:
+            t = _aligned_divisor(p, target, ATTN_LANE)
+            if t > best:
+                padded, best = p, t
+    return padded
+
+
+def _attention_tile(padded: int, target: int, lane: int = ATTN_LANE) -> int:
+    """Largest ``lane``-aligned divisor of ``padded`` ≤ ``target``; a short
+    axis is one tile."""
+    if padded <= ATTN_LANE:
+        return padded
+    return _aligned_divisor(padded, max(target, ATTN_LANE), lane)
+
+
 def attention_tiles(
     lq: int,
     lk: int,
@@ -223,21 +258,27 @@ def attention_tiles(
     """Resolve two-stage attention tiles -> ``({bq, bk, bkv}, lqp, lkp)``.
 
     Explicit ``bq``/``bk``/``bkv`` must divide exactly (legacy behavior,
-    no padding); ``*_target`` values — the form schedules carry — go
-    through :func:`lane_tile` like the default T_Q/T_K/T_V policy.
+    no padding).  ``*_target`` values — the form schedules carry — and the
+    T_Q/T_K/T_V defaults pad each token axis once, by the key tile's rule
+    (:func:`_attention_axis`, so a self-attention pads both axes alike),
+    then take 128-aligned ``bk`` and ``bkv`` (both divide ``lkp``) and an
+    8-aligned ``bq`` of the padded lengths.
     """
     tiles: dict[str, int] = {}
+    bk_target = bk_target or _tsa.T_K
     if bq is not None:
         tiles["bq"], lqp = bq, lq
     else:
-        tiles["bq"], lqp = lane_tile(lq, bq_target or _tsa.T_Q)
+        lqp = _attention_axis(lq, bk_target)
+        tiles["bq"] = _attention_tile(lqp, bq_target or _tsa.T_Q, LANE)
     if bk is not None or bkv is not None:
         lkp = lk
         tiles["bk"] = bk if bk is not None else divisor_tile(lk, _tsa.T_K)
         tiles["bkv"] = bkv if bkv is not None else divisor_tile(lk, _tsa.T_V)
     else:
-        tiles["bk"], lkp = lane_tile(lk, bk_target or _tsa.T_K)
-        tiles["bkv"], _ = lane_tile(lk, bkv_target or _tsa.T_V)
+        lkp = _attention_axis(lk, bk_target)
+        tiles["bk"] = _attention_tile(lkp, bk_target)
+        tiles["bkv"] = _attention_tile(lkp, bkv_target or _tsa.T_V)
     return tiles, lqp, lkp
 
 
@@ -255,7 +296,7 @@ def matmul_tile_seed(k: int, n: int, *, packed: bool = False, fused: bool = Fals
 
 
 def attention_tile_seed() -> dict:
-    """Default two-stage attention tile targets (paper's T_Q/T_K/T_V)."""
+    """Default two-stage attention tile targets (T_Q/T_K/T_V)."""
     return {"bq_target": _tsa.T_Q, "bk_target": _tsa.T_K, "bkv_target": _tsa.T_V}
 
 
@@ -306,11 +347,13 @@ def two_stage_mha(
     they are never broadcast-copied to the full head count.  Returns
     [B, H, Lq, dh] float32.
 
-    Tile sizes not passed explicitly default to lane-aligned tiles under
-    the paper's T_Q/T_K/T_V, padding Lq (garbage rows sliced off) and Lk
-    (tail keys masked in-kernel via ``kv_len``) when no healthy divisor
-    exists.  Explicitly passed tiles must divide exactly (legacy behavior).
-    ``role`` names the two launches (``two_stage_attention``'s ``role``).
+    Tile sizes not passed explicitly resolve through
+    :func:`attention_tiles`: each token axis is padded to a multiple of
+    128, Lq's garbage rows sliced off and Lk's tail keys masked in-kernel
+    via ``kv_len``.  Explicitly passed tiles must divide exactly (legacy
+    behavior).  ``role`` names the two launches (``two_stage_attention``'s
+    ``role``).  Each trace emits one ``attn.tiles`` event (``obs.trace``)
+    with the resolved tiles, padded lengths and stage ①'s grid steps.
     """
     interpret = _default_interpret() if interpret is None else interpret
     b, h, lq, dh = q.shape
@@ -318,6 +361,10 @@ def two_stage_mha(
     assert h % hkv == 0, (h, hkv)
 
     tile_kw, lqp, lkp = attention_tiles(lq, lk, **tile_kw)
+    obs_trace.emit(
+        "attn.tiles", role=role, **tile_kw, lqp=lqp, lkp=lkp,
+        stage1_steps=b * h * (lqp // tile_kw["bq"]) * (lkp // tile_kw["bk"]),
+    )
 
     qf = q.reshape(b * h, lq, dh)
     kf = k.reshape(b * hkv, lk, dh)

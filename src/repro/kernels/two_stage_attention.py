@@ -4,7 +4,7 @@ The paper's answer to VGGT's long-sequence global attention: instead of
 FlashAttention's single pass (which must carry a running O accumulator and
 rescale it whenever the row max moves), split the work into
 
-  **Stage ①** — stream small K tiles against each Q tile and maintain only
+  **Stage ①** — stream K tiles against each Q tile and maintain only
   the softmax statistics ``M`` (row max) and ``Σ`` (row sum), Eq. 8-9.
   No V traffic, no O accumulator: the VMEM working set is one Q tile, one
   K tile and two [T_Q, 1] vectors.
@@ -21,10 +21,15 @@ the probabilities to INT8 (line 11) so the P·V matmul also hits the MXU in
 int8 — V therefore carries a per-head (per-tensor) scale, since a
 per-token V scale would not factor out of the contraction.
 
-Tile configuration mirrors the paper (T_Q = T_K = 64 for Stage ①,
-T_V = 2048 mega-tiles for Stage ②) but is parameterized; the Stage-②
-kernel is also exposed with FlashAttention-style fused stats for the
-roofline comparison in benchmarks/fig13.
+Tiles.  The paper's T_Q = T_K = 64 (Stage ①) and T_V = 2048 (Stage ②)
+size its ASIC's buffers.  On a TPU v5e a grid step costs a fixed
+0.35–0.4 µs whatever its tile holds, and a score tile lies on the 128-lane
+vreg with its key axis on the lanes, so the tiles here follow the vreg and
+the MXU instead: ``kernels.ops.attention_tiles`` pads each token axis to
+a multiple of 128 and picks 128-aligned tiles of tens to hundreds of
+thousands of scores (T_Q/T_K/T_V below are its targets, chosen by a sweep
+on the chip).  Stage ① masks the padded keys only in the key tiles that
+reach past ``kv_len``.
 """
 from __future__ import annotations
 
@@ -37,42 +42,55 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-T_Q = 64
-T_K = 64
-T_V = 2048
+# Tile targets (``kernels.ops.attention_tiles`` resolves them per length),
+# from a sweep of both stages at VGGT-1B's frame and global lengths on a
+# TPU v5e (benchmarks/attn_tile_sweep.py): every larger tile was faster,
+# up to 512 query rows and whole key rows of 2,816.
+T_Q = 512
+T_K = 4096
+T_V = 4096
 
 
 def _stage1_kernel(
     qv_ref, kv_ref, qs_ref, ks_ref, m_ref, l_ref, m_acc, l_acc, *, nk, scale, causal,
     bq, bk, kv_len
 ):
-    j = pl.program_id(2)
+    i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         m_acc[...] = jnp.full_like(m_acc, NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
 
-    s = jax.lax.dot_general(
-        qv_ref[0],
-        kv_ref[0],
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-    s = s.astype(jnp.float32) * qs_ref[0] * ks_ref[0].T * scale  # dequant (line 4)
-    if causal or kv_len is not None:
-        cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if causal:
-            i = pl.program_id(1)
-            rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        if kv_len is not None:  # lane-padding tail keys are not real
-            s = jnp.where(cols < kv_len, s, NEG_INF)
-    m_new = jnp.maximum(m_acc[...], s.max(axis=-1, keepdims=True))  # Eq. 8
-    l_acc[...] = l_acc[...] * jnp.exp(m_acc[...] - m_new) + jnp.exp(s - m_new).sum(
-        axis=-1, keepdims=True
-    )  # Eq. 9
-    m_acc[...] = m_new
+    def accumulate(kv_len):
+        s = jax.lax.dot_general(
+            qv_ref[0],
+            kv_ref[0],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+        s = s.astype(jnp.float32) * qs_ref[0] * ks_ref[0].T * scale  # dequant (line 4)
+        if causal or kv_len is not None:
+            cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            if causal:
+                rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                s = jnp.where(rows >= cols, s, NEG_INF)
+            if kv_len is not None:  # padded tail keys are not real
+                s = jnp.where(cols < kv_len, s, NEG_INF)
+        m_new = jnp.maximum(m_acc[...], s.max(axis=-1, keepdims=True))  # Eq. 8
+        l_acc[...] = l_acc[...] * jnp.exp(m_acc[...] - m_new) + jnp.exp(s - m_new).sum(
+            axis=-1, keepdims=True
+        )  # Eq. 9
+        m_acc[...] = m_new
+
+    if kv_len is None:
+        accumulate(None)
+    else:
+        # only the key tiles that reach past kv_len pay for the mask
+        first_padded = kv_len // bk
+        if first_padded > 0:
+            pl.when(j < first_padded)(lambda: accumulate(None))
+        pl.when(j >= first_padded)(lambda: accumulate(kv_len))
 
     @pl.when(j == nk - 1)
     def _fin():
@@ -144,6 +162,79 @@ def _launch_name(role: str | None, stage: str) -> str | None:
     return None if role is None else f"two_stage_attention_{role}_{stage}"
 
 
+def _kv_row_map(bh: int, kv_bh: int, q_heads: int | None, kv_heads: int | None):
+    """Grid row of K/V for query row ``b``: GQA query heads share a K/V
+    head, gathered by the index map instead of a broadcast copy."""
+    if q_heads is not None and kv_heads is not None and q_heads != kv_heads:
+        assert q_heads % kv_heads == 0, (q_heads, kv_heads)
+        assert bh % q_heads == 0 and kv_bh == bh // q_heads * kv_heads
+        g = q_heads // kv_heads
+        return lambda b: (b // q_heads) * kv_heads + (b % q_heads) // g
+    assert kv_bh == bh, (kv_bh, bh)
+    return lambda b: b
+
+
+def attention_stats(
+    qv: jnp.ndarray,
+    qs: jnp.ndarray,
+    kv: jnp.ndarray,
+    ks: jnp.ndarray,
+    *,
+    causal: bool = False,
+    scale: float | None = None,
+    bq: int = T_Q,
+    bk: int = T_K,
+    interpret: bool = False,
+    q_heads: int | None = None,
+    kv_heads: int | None = None,
+    kv_len: int | None = None,
+    role: str | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Stage ① alone: the row max ``M`` and row sum ``Σ`` ([BH, Lq, 1] f32)
+    of the dequantized scores, over a (BH, Lq/bq, Lk/bk) grid.  Arguments
+    as :func:`two_stage_attention`'s."""
+    bh, lq, dh = qv.shape
+    lk = kv.shape[1]
+    scale = scale if scale is not None else 1.0 / (dh**0.5)
+    bq = min(bq, lq)
+    bk = min(bk, lk)
+    assert lq % bq == 0 and lk % bk == 0, (lq, bq, lk, bk)
+    nq, nk = lq // bq, lk // bk
+    if kv_len is not None and kv_len >= lk:
+        kv_len = None
+    kv_row = _kv_row_map(bh, kv.shape[0], q_heads, kv_heads)
+    return pl.pallas_call(
+        functools.partial(
+            _stage1_kernel, nk=nk, scale=scale, causal=causal, bq=bq, bk=bk,
+            kv_len=kv_len,
+        ),
+        grid=(bh, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, dh), lambda b, i, j: (kv_row(b), j, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, 1), lambda b, i, j: (kv_row(b), j, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+        ],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        name=_launch_name(role, "stats"),
+    )(qv, kv, qs, ks)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -195,57 +286,18 @@ def two_stage_attention(
     lk = kv.shape[1]
     scale = scale if scale is not None else 1.0 / (dh**0.5)
     bq = min(bq, lq)
-    bk = min(bk, lk)
     bkv = min(bkv, lk)
-    assert lq % bq == 0 and lk % bk == 0 and lk % bkv == 0
-    nq, nk, nkv = lq // bq, lk // bk, lk // bkv
+    assert lk % bkv == 0
+    nq, nkv = lq // bq, lk // bkv
     if kv_len is not None and kv_len >= lk:
         kv_len = None  # nothing padded: skip the mask
+    kv_row = _kv_row_map(bh, kv.shape[0], q_heads, kv_heads)
 
-    if q_heads is not None and kv_heads is not None and q_heads != kv_heads:
-        assert q_heads % kv_heads == 0, (q_heads, kv_heads)
-        assert bh % q_heads == 0 and kv.shape[0] == bh // q_heads * kv_heads
-        g = q_heads // kv_heads
-
-        def kv_row(b):
-            return (b // q_heads) * kv_heads + (b % q_heads) // g
-    else:
-        assert kv.shape[0] == bh, (kv.shape, bh)
-
-        def kv_row(b):
-            return b
-
-    # Stage ①: softmax statistics only
-    m, l = pl.pallas_call(
-        functools.partial(
-            _stage1_kernel, nk=nk, scale=scale, causal=causal, bq=bq, bk=bk,
-            kv_len=kv_len,
-        ),
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (kv_row(b), j, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, 1), lambda b, i, j: (kv_row(b), j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
-            jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-        ],
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        name=_launch_name(role, "stats"),
-    )(qv, kv, qs, ks)
+    m, l = attention_stats(
+        qv, qs, kv, ks, causal=causal, scale=scale, bq=bq, bk=bk,
+        interpret=interpret, q_heads=q_heads, kv_heads=kv_heads, kv_len=kv_len,
+        role=role,
+    )
 
     # Stage ②: recompute with mega-tiles, final stats as inputs
     out = pl.pallas_call(

@@ -10,7 +10,8 @@ panels resident, so ``FUSED_PANEL_BUDGET`` is checked here against what
 the compiler accepts.
 
 Token counts are a VGGT-1B scene's global attention length:
-S frames × (1,369 patches + 5 special tokens), for S = 8 and 32.
+S frames × (1,369 patches + 5 special tokens), for S = 8 and 32, and for
+a batch of eight 2-frame scenes.
 """
 import re
 
@@ -89,8 +90,8 @@ def _compile(fn, *args) -> str:
 
 @pytest.mark.parametrize(
     "batch,length",
-    [(1, SCENE_TOKENS[0]), (1, SCENE_TOKENS[1]), (8, FRAME_TOKENS)],
-    ids=["global-S8", "global-S32", "frame"],
+    [(1, SCENE_TOKENS[0]), (1, SCENE_TOKENS[1]), (8, FRAME_TOKENS), (8, 2 * FRAME_TOKENS)],
+    ids=["global-S8", "global-S32", "frame", "global-S2-batch8"],
 )
 def test_two_stage_attention_compiles(one_chip, batch, length):
     qkv = _spec(one_chip, (batch, 16, length, 64))

@@ -6,7 +6,12 @@ import pytest
 
 from repro.core.quantize import quantize_per_token
 from repro.kernels import ops, ref
-from repro.kernels.two_stage_attention import two_stage_attention, vmem_bytes_two_stage
+from repro.kernels.two_stage_attention import (
+    attention_stats,
+    two_stage_attention,
+    vmem_bytes_two_stage,
+)
+from repro.obs import trace as obs_trace
 
 RNG = np.random.default_rng(3)
 
@@ -92,7 +97,8 @@ def test_stats_match_flash_semantics():
 
 def test_model_path_non_divisible_length_divisor_tiles():
     """ops.two_stage_mha on L = 4·(5+64) = 276 — the serving engine's
-    global-attention length — picks divisor tiles and stays close to fp."""
+    global-attention length, with no 8-aligned divisor tile near 64 — pads
+    to a tileable length and stays close to fp."""
     from repro.kernels.ops import divisor_tile
 
     b, h, l, dh = 1, 2, 276, 32
@@ -231,3 +237,118 @@ def test_gqa_model_path_no_kv_broadcast():
     want, _ = lm.forward(e_cfg, qp, toks)
     rel = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
     assert rel < 0.05, rel
+
+
+# ---------------------------------------------------------------------------
+# Default tiles at VGGT-1B's lengths: a frame (1,369 patches + 5 special
+# tokens), two frames (a batched 2-frame scene's global attention), 8 and
+# 32 frames, and a short power of two.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "length,lp,bq,bk,bkv",
+    [
+        (256, 256, 256, 256, 256),
+        (1374, 1408, 352, 1408, 1408),
+        (2748, 2816, 352, 2816, 2816),
+        (10992, 11264, 512, 2816, 2816),
+        (43968, 44032, 512, 1024, 1024),
+    ],
+)
+def test_default_tiles_at_vggt_lengths(length, lp, bq, bk, bkv):
+    """Each token axis is padded once, to a multiple of 128 (11,008 would
+    allow only 256-key tiles, so 10,992 goes to 11,264), by at most 3%;
+    key tiles lie on the 128 lanes and every stage ① tile holds at least
+    64K scores from 1,024 tokens up."""
+    tiles, lqp, lkp = ops.attention_tiles(length, length)
+    assert tiles == {"bq": bq, "bk": bk, "bkv": bkv}
+    assert lqp == lkp == lp
+    assert lp - length <= 0.03 * length
+    assert lp % 128 == 0 and bk % 128 == 0 and bkv % 128 == 0 and bq % 8 == 0
+    assert lp % bq == 0 and lp % bk == 0 and lp % bkv == 0
+    if length >= 1024:
+        assert bq * bk >= 64 * 1024
+
+
+@pytest.mark.parametrize("l", [1374, 2748])
+def test_default_tiles_match_fp_and_exact_row_max(l):
+    """At a frame's and a 2-frame scene's length the default (padded)
+    tiles stay within the fp tolerance, and stage ①'s row max over the
+    padded keys equals the row max over the real keys bit for bit."""
+    b, h, dh = 1, 2, 64
+    q, k, v = (jnp.asarray(RNG.normal(size=(b, h, l, dh)), jnp.float32) for _ in range(3))
+    got = ops.two_stage_mha(q, k, v)
+    fp = ref.attention_ref(q, k, v, causal=False)
+    rel = float(jnp.linalg.norm(got - fp) / jnp.linalg.norm(fp))
+    assert rel < 0.05, rel
+
+    tiles, lqp, lkp = ops.attention_tiles(l, l)
+    qq = quantize_per_token(q.reshape(b * h, l, dh), 8)
+    kq = quantize_per_token(k.reshape(b * h, l, dh), 8)
+    qs, ks = qq.scale.astype(jnp.float32), kq.scale.astype(jnp.float32)
+
+    def pad(x, n, fill=0):
+        return jnp.pad(x, ((0, 0), (0, n - l), (0, 0)), constant_values=fill)
+
+    m, _ = attention_stats(
+        pad(qq.values, lqp), pad(qs, lqp, 1), pad(kq.values, lkp), pad(ks, lkp, 1),
+        bq=tiles["bq"], bk=tiles["bk"], kv_len=l, interpret=True,
+    )
+    s_int = jnp.einsum(
+        "bqd,bkd->bqk", qq.values.astype(jnp.int32), kq.values.astype(jnp.int32)
+    )
+    want = (s_int.astype(jnp.float32) * qs * jnp.swapaxes(ks, 1, 2) * (1.0 / dh**0.5)).max(
+        -1, keepdims=True
+    )
+    np.testing.assert_array_equal(np.asarray(m[:, :l]), np.asarray(want))
+
+
+def test_attn_tiles_event_once_per_compile():
+    """One ``attn.tiles`` event per traced ``two_stage_mha``: the resolved
+    tiles, the padded lengths and stage ①'s grid steps; a second call of
+    the compiled function emits nothing."""
+    b, h, l, dh = 1, 2, 300, 32
+    q = jnp.asarray(RNG.normal(size=(b, h, l, dh)), jnp.float32)
+    f = jax.jit(lambda q, k, v: ops.two_stage_mha(q, k, v, role="global"))
+    tracer = obs_trace.Tracer()
+    prev = obs_trace.install(tracer)
+    try:
+        f(q, q, q)
+        f(q, q, q)
+    finally:
+        obs_trace.install(prev)
+    events = [e for e in tracer.recent() if e.phase == "attn.tiles"]
+    tiles, lqp, lkp = ops.attention_tiles(l, l)
+    steps = b * h * (lqp // tiles["bq"]) * (lkp // tiles["bk"])
+    assert [e.labels for e in events] == [
+        {"role": "global", **tiles, "lqp": lqp, "lkp": lkp, "stage1_steps": steps}
+    ]
+
+
+def test_vggt_forward_emits_attn_tiles_per_role():
+    """A traced VGGT forward emits one ``attn.tiles`` event per role: the
+    24-layer scan traces each block's attention once."""
+    from repro.configs import get_config
+    from repro.core.model_quant import quantize_vggt
+    from repro.core.versaq import W4A8
+    from repro.models import vggt
+
+    cfg = get_config("vggt-1b-smoke").with_(
+        n_layers=2, d_model=64, n_heads=2, n_kv_heads=2, head_dim=32, d_ff=128,
+        attn_impl="two_stage",
+    )
+    qp = quantize_vggt(cfg, vggt.init_params(cfg, jax.random.PRNGKey(0)), W4A8)
+    x = jax.ShapeDtypeStruct((1, 3, 11, cfg.d_model), jnp.float32)
+    tracer = obs_trace.Tracer()
+    prev = obs_trace.install(tracer)
+    try:
+        jax.eval_shape(lambda p, x: vggt.forward(cfg, p, x), qp, x)
+    finally:
+        obs_trace.install(prev)
+    events = [e.labels for e in tracer.recent() if e.phase == "attn.tiles"]
+    t = 11 + cfg.n_special_tokens
+    assert sorted((e["role"], e["lqp"]) for e in events) == [
+        ("frame", ops.attention_tiles(t, t)[1]),
+        ("global", ops.attention_tiles(3 * t, 3 * t)[1]),
+    ]
