@@ -6,8 +6,12 @@ defaults, started with a metrics port, which is how a deployment exposes
 ``/metrics`` and which turns the program's telemetry on (span events,
 kernel counters, quant-health monitors) before anything is traced.
 
-This module is the only one of the benchmark that imports the program;
-``src`` must be importable (``run.py`` puts it on the path).
+This module and the program modules that configurations name are the
+only ones of the benchmark that import the program; ``src`` must be
+importable (``run.py`` puts it on the path).  A configuration whose
+weights map to the program differently names a module of its own that
+imports this one and passes ``serve`` its own ``to_params`` or
+``model_config``.
 """
 from __future__ import annotations
 
@@ -62,11 +66,14 @@ def model_config(config: dict):
     )
 
 
-def serve(config: dict, weights: dict, max_batch: int, policy: str | None = None):
+def serve(config: dict, weights: dict, max_batch: int, policy: str | None = None, *,
+          to_params=to_params, model_config=model_config):
     """Quantize the weights and start the server; returns (server, engine).
 
     ``policy`` replaces the configuration's precision (the control runs
-    the program's lower-precision path through the same server)."""
+    the program's lower-precision path through the same server);
+    ``to_params`` and ``model_config`` map the configuration and its
+    weights to the program's."""
     from repro.launch.specs import ServeSpec
     from repro.serving.server import AsyncServer
     from repro.serving.vggt_engine import VGGTEngine

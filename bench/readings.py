@@ -15,6 +15,7 @@ reads the cell's traffic with another configuration file.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -39,7 +40,8 @@ def main() -> int:
     cell = run.load_cell(args.workload)
     if args.config:
         with open(args.config) as f:
-            cell.config = json.load(f)
+            config = json.load(f)
+        cell = dataclasses.replace(cell, config=config, **run.modules(config))
     run.configure_cache()
     for seed in (int(s) for s in args.seeds.split(",")):
         t = time.perf_counter()

@@ -27,6 +27,10 @@ tell the two apart.
 
 The block sizes are the configuration's: the largest power of two that
 divides a width (at most ``hadamard_block_cap``) for H, ``dct_block`` for D.
+
+A configuration whose blocks differ names a reference module of its own
+that subclasses ``Reference`` and overrides the methods that differ:
+``_prep_block`` (offline), ``_block`` or ``_attention`` (online).
 """
 from __future__ import annotations
 
@@ -178,8 +182,12 @@ class Reference:
             y = _blocked(y, self.dct)
         return y + b
 
-    def _attention(self, q, k, v):
-        """[N, L, H, dh] each -> [N, L, H·dh]; one head at a time."""
+    def _attention(self, p, q, k, v, t):
+        """[N, L, H, dh] each, q and k before their per-head WHT, ->
+        [N, L, H·dh]; one head at a time.  ``p`` is the block's prepared
+        weights and ``t`` the tokens of one frame (L is a multiple of it),
+        for a configuration whose q and k depend on a token's place."""
+        q, k = wht(q, self.cap), wht(k, self.cap)
         bits = int(self.c["attn_bits"])
         qmax = 2.0 ** (bits - 1) - 1
         dh = q.shape[-1]
@@ -194,15 +202,16 @@ class Reference:
             o = jnp.einsum("nqk,nkd->nqd", jnp.round(p * qmax), vi)
             return o * (sv / qmax) / jnp.sum(p, -1, keepdims=True)
 
-        o = jax.lax.map(head, tuple(jnp.moveaxis(t, 2, 0) for t in (q, k, v)))
+        o = jax.lax.map(head, tuple(jnp.moveaxis(u, 2, 0) for u in (q, k, v)))
         return jnp.moveaxis(o, 0, 2).reshape(q.shape[:2] + (-1,))
 
-    def _block(self, p, x):
-        n, t, d = x.shape
+    def _block(self, p, x, t):
+        """One AA block over x [N, L, d]; ``t`` as in ``_attention``."""
+        n, length, d = x.shape
         nh, dh = int(self.c["n_heads"]), int(self.c["head_dim"])
         qkv = self._linear(self._norm(x), p["w_qkv"], p["s_qkv"], p["b_qkv"])
-        q, k, v = (u.reshape(n, t, nh, dh) for u in jnp.split(qkv, 3, axis=-1))
-        o = self._attention(wht(q, self.cap), wht(k, self.cap), v)
+        q, k, v = (u.reshape(n, length, nh, dh) for u in jnp.split(qkv, 3, axis=-1))
+        o = self._attention(p, q, k, v, t)
         x = x + self._linear(o, p["w_o"], p["s_o"], p["b_o"])
         h = self._linear(self._norm(x), p["w_up"], p["s_up"], p["b_up"])
         h = wht(jax.nn.gelu(h, approximate=True), self.cap)
@@ -220,8 +229,8 @@ class Reference:
             t = ns + p_
 
             def pair(xc, bp):
-                xc = self._block(bp["frame"], xc.reshape(b * s, t, d))
-                xc = self._block(bp["global"], xc.reshape(b, s * t, d))
+                xc = self._block(bp["frame"], xc.reshape(b * s, t, d), t)
+                xc = self._block(bp["global"], xc.reshape(b, s * t, d), t)
                 return xc.reshape(b, s, t, d), None
 
             x, _ = jax.lax.scan(pair, x, prep["blocks"])

@@ -5,21 +5,35 @@
 Run from the root of a checkout.  The cell names a configuration and a
 traffic mix; both are files found by name (``bench/configs/<name>.json``,
 ``bench/traffic/<name>.json``), and each per-layer metric is a reader in
-``bench/metrics/<name>.py``.  A run
+``bench/metrics/<name>.py``.  The configuration names, under ``weights``,
+``program`` and ``reference``, the three modules ``bench/<name>.py`` that
+draw its weights, hand them to the program and compute its reference.
+Each keeps this interface (``MODULES``):
+
+* weights: ``init(config, seed) -> tree``, the plain weights on the device;
+* program: ``serve(config, tree, max_batch, policy) -> (server, engine)``,
+  ``telemetry()`` (the program's span events so far) and
+  ``shutdown(server)``;
+* reference: ``Reference(config)``, with ``prepare(tree)`` and
+  ``forward(prepared, patches)``, which returns every output in
+  ``COMPARED``.
+
+A run
 
 1. refuses to measure without a TPU (exit code 3, no result line);
-2. sets up: weights from the seed on the device, the program's server
-   (``program.serve``), the traffic's scenes, one round of traffic through
-   the server to compile and warm every shape the window uses;
+2. sets up: weights from the seed on the device, the program's server,
+   the traffic's scenes, one round of traffic through the server to
+   compile and warm every shape the window uses;
 3. measures a closed loop of ``clients`` callers for ``--seconds``: each
    submits a scene, waits for its result and submits the next.  The window
    closes at the first forward that completes after ``--seconds``, and
    counts every scene that forward delivered;
 4. with ``--trace 1`` records a profiler trace of the window and reports
-   the per-layer metrics that list the cell (one that reads nothing ends
-   the run with exit code 4), otherwise its end-to-end metrics;
+   the per-layer metrics that list the cell (one whose reader finds
+   nothing to read is left out of the line), otherwise its end-to-end
+   metrics;
 5. frees the program and compares what the window returned, for a sample
-   of its scenes drawn from the seed, with ``reference.py`` (``COMPARED``);
+   of its scenes drawn from the seed, with the reference (``COMPARED``);
    each number compared is printed beside its limit, on stderr and under
    ``check``.
 
@@ -51,8 +65,7 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
 
 import jax  # noqa: E402
 
-from bench import program, scenes, weights, work, xtrace  # noqa: E402
-from bench.reference import Reference  # noqa: E402
+from bench import scenes, work, xtrace  # noqa: E402
 
 RESULT_TIMEOUT_S = 900  # one forward of the largest cell takes about 12 s
 CLOSE_GRACE_S = 1.0  # co-batched scenes of the closing forward arrive within it
@@ -68,13 +81,12 @@ COMPARED = {
     "dpt": ("points", "depth", "conf"),  # the DPT head's outputs
     "pose": ("pose",),  # the camera head
 }
+# The modules a configuration names, and what each must define.
+MODULES = {"weights": ("init",), "program": ("serve", "telemetry", "shutdown"),
+           "reference": ("Reference",)}
 
 
 class NoChip(RuntimeError):
-    pass
-
-
-class MissingMetric(RuntimeError):
     pass
 
 
@@ -86,6 +98,9 @@ class Cell:
     traffic: dict
     end_to_end: list  # every end-to-end metric of BENCHMARK.json
     per_layer: list  # the per-layer metrics that list this cell
+    weights: object  # the configuration's modules (``MODULES``)
+    program: object
+    reference: object
 
 
 def load_cell(workload: str, root: str = ROOT) -> Cell:
@@ -102,18 +117,40 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
         with open(os.path.join(root, "bench", kind, name + ".json")) as f:
             return json.load(f)
 
+    config = data("configs", w["config"])
     layer = [m for m in bench["per_layer"] if workload in m["workloads"]]
-    return Cell(workload, int(w["chips"]), data("configs", w["config"]),
-                data("traffic", w["traffic"]), bench["end_to_end"], layer)
+    return Cell(workload, int(w["chips"]), config, data("traffic", w["traffic"]),
+                bench["end_to_end"], layer, **modules(config, root))
+
+
+def _load(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def modules(config: dict, root: str = ROOT) -> dict:
+    """The configuration's weights, program and reference modules, each
+    ``bench/<name>.py`` as its key in the configuration names it."""
+    out, where = {}, f"configuration {config.get('name')!r}"
+    for kind, names in MODULES.items():
+        if not isinstance(config.get(kind), str):
+            raise KeyError(f"{where} names no {kind} module (key {kind!r})")
+        path = os.path.join(root, "bench", config[kind] + ".py")
+        if not os.path.isfile(path):
+            raise FileNotFoundError(f"{where}: no {kind} module {path}")
+        mod = _load(path, f"bench_{kind}_{config[kind]}")
+        missing = [n for n in names if not hasattr(mod, n)]
+        if missing:
+            raise AttributeError(f"{where}: {kind} module {path} lacks {missing}")
+        out[kind] = mod
+    return out
 
 
 def reader(name: str, root: str = ROOT):
     """``read(measured) -> float | None`` of ``bench/metrics/<name>.py``."""
-    path = os.path.join(root, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(os.path.join(root, "bench", "metrics", name + ".py"), f"bench_metric_{name}").read
 
 
 def device_info(chips: int, require_tpu: bool = True) -> dict:
@@ -245,12 +282,16 @@ def rel_gap(got, ref) -> float:
 
 
 def layer_metrics(cell: Cell, m: Measured, root: str = ROOT) -> dict:
-    """The cell's per-layer metrics; each lists the cell, so each must read."""
+    """The cell's per-layer metrics; one whose reader finds nothing to read
+    (a span or kernel the program no longer has) is left out, and named on
+    stderr."""
     out = {}
     for spec in cell.per_layer:
         v = reader(spec["name"], root)(m)
         if v is None:
-            raise MissingMetric(f"{spec['name']} read nothing in {cell.name}")
+            print(f"warning: {spec['name']} read nothing in {cell.name}; left out",
+                  file=sys.stderr)
+            continue
         out[spec["name"]] = {"value": v, "unit": spec["unit"]}
     return out
 
@@ -270,11 +311,11 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, *, root: str = R
     frames, patches, clients = int(tr["frames"]), int(tr["patches"]), int(tr["clients"])
 
     # ---- set-up --------------------------------------------------------
-    w = jax.block_until_ready(weights.init(c, seed))
+    w = jax.block_until_ready(cell.weights.init(c, seed))
     pool = [jax.device_put(scenes.scene(frames, patches, c["d_model"], i, seed))
             for i in range(int(tr["scene_pool"]))]
     order = np.random.default_rng(np.random.SeedSequence([seed, 1])).permutation(len(pool))
-    srv, eng = program.serve(c, w, int(tr["max_batch"]), policy=policy)
+    srv, eng = cell.program.serve(c, w, int(tr["max_batch"]), policy=policy)
     try:
         # warm-up: one scene per client, all in flight at once, so that it
         # fills the batch bucket the window fills
@@ -305,9 +346,9 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, *, root: str = R
         in_window = compiles.n
         mem = max(d.memory_stats().get("peak_bytes_in_use", 0)
                   for d in jax.devices()[: cell.chips]) if require_tpu else 0
-        events = program.telemetry()
+        events = cell.program.telemetry()
     finally:
-        program.shutdown(srv)
+        cell.program.shutdown(srv)
     stats = {k: after[k] - before[k] for k in after}
     print(f"info: setup_s {setup_s:.3f}, window {t_close - t0:.3f} s, scenes {len(counted)}, "
           f"failed {failed}, forwards {stats['calls']}, compiles in the window: "
@@ -353,7 +394,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, *, root: str = R
     del results, pool, srv, eng
     gc.unfreeze()
     gc.collect()
-    ref = Reference(c)
+    ref = cell.reference.Reference(c)
     prep = ref.prepare(w)
     del w
     err2 = {n: 0.0 for n in limits}
@@ -400,9 +441,6 @@ def main(argv=None) -> int:
     except NoChip as e:
         print(f"error: {e}", file=sys.stderr)
         return 3
-    except MissingMetric as e:
-        print(f"error: {e}", file=sys.stderr)
-        return 4
     for k, v in result["check"].items():
         print(f"check: {k} {v['value']!r} <= {v['limit']!r}", file=sys.stderr)
     sys.stderr.flush()

@@ -136,13 +136,13 @@ def test_span_readers_on_a_window_the_program_served(tmp_path):
     something to read."""
     import jax
 
-    from bench import program, scenes, weights
+    from bench import scenes
 
     seed = 2**31 + 7
     root = tiny.make_root(tmp_path)
     cell = run.load_cell(tiny.CELL, root=root)
-    c, tr = cell.config, cell.traffic
-    srv, _ = program.serve(c, weights.init(c, seed), int(tr["max_batch"]))
+    c, tr, program = cell.config, cell.traffic, cell.program
+    srv, _ = program.serve(c, cell.weights.init(c, seed), int(tr["max_batch"]))
     try:
         reqs = [srv.submit(jax.device_put(scenes.scene(tr["frames"], tr["patches"],
                                                        c["d_model"], i, seed)))

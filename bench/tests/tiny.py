@@ -12,9 +12,9 @@ CELL = "tiny.t2"
 
 
 def make_root(path, check_scenes=2, d_model=128) -> str:
-    """Write BENCHMARK.json and bench/{configs,traffic,metrics,peaks.json}
-    under ``path``, the model ``d_model`` wide (the FFN twice that);
-    returns ``path`` as a string."""
+    """Write BENCHMARK.json, bench/{configs,traffic,metrics,peaks.json}
+    and the modules the configuration names under ``path``, the model
+    ``d_model`` wide (the FFN twice that); returns ``path`` as a string."""
     root = str(path)
     os.makedirs(os.path.join(root, "bench", "configs"))
     os.makedirs(os.path.join(root, "bench", "traffic"))
@@ -24,6 +24,8 @@ def make_root(path, check_scenes=2, d_model=128) -> str:
         c = json.load(f)
     c.update(name="tiny", n_aa_pairs=2, d_model=d_model, n_heads=4, head_dim=32,
              d_ff=2 * d_model)
+    for kind in ("weights", "program", "reference"):
+        shutil.copy(os.path.join(BENCH, c[kind] + ".py"), os.path.join(root, "bench"))
     with open(os.path.join(root, "bench", "configs", "tiny.json"), "w") as f:
         json.dump(c, f)
     traffic = {"loop": "closed", "clients": 2, "frames": 2, "patches": 16, "max_batch": 2,

@@ -9,6 +9,9 @@ biases are drawn at a trained-like scale from the configuration's
 (the attention projections carry ``qkv``/``proj`` biases where
 ``attn_bias`` says so), so that the comparison sees every bias fold and
 epilogue.  ``init["zero"]`` names the tensors drawn as 0 instead.
+
+A configuration whose blocks hold other tensors draws them in a module of
+its own that passes ``init`` another ``block``.
 """
 from __future__ import annotations
 
@@ -63,15 +66,15 @@ def _block(key, c: dict) -> dict:
     return p
 
 
-@functools.partial(jax.jit, static_argnums=1)
-def _init(key, frozen: tuple) -> dict:
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _init(key, frozen: tuple, block) -> dict:
     c = dict(frozen)
     c["init"] = dict(c["init"])
     d = c["d_model"]
     ks = jax.random.split(key, 16)
     pairs = c["n_aa_pairs"]
     blocks = {
-        name: jax.vmap(lambda k: _block(k, c))(jax.random.split(kb, pairs))
+        name: jax.vmap(lambda k: block(k, c))(jax.random.split(kb, pairs))
         for name, kb in (("frame", ks[0]), ("global", ks[1]))
     }
     return {
@@ -88,13 +91,13 @@ def _init(key, frozen: tuple) -> dict:
     }
 
 
-SHAPE_KEYS = ("n_aa_pairs", "d_model", "n_heads", "head_dim", "d_ff",
-              "n_special_tokens", "layerscale", "attn_bias")
-
-
-def init(config: dict, seed: int) -> dict:
-    """The configuration's weights for ``seed`` (float32, on the default device)."""
+def init(config: dict, seed: int, block=_block) -> dict:
+    """The configuration's weights for ``seed`` (float32, on the default
+    device); ``block(key, config) -> dict`` draws one AA block's tensors.
+    ``block`` sees the configuration's top-level numbers, flags and names
+    and its ``init``."""
     init_ = tuple((k, tuple(v) if isinstance(v, list) else v)
                   for k, v in sorted(config["init"].items()))
-    frozen = tuple((k, config[k]) for k in SHAPE_KEYS) + (("init", init_),)
-    return _init(key_for(seed, 0), frozen)
+    frozen = tuple((k, v) for k, v in sorted(config.items())
+                   if isinstance(v, (bool, int, float, str))) + (("init", init_),)
+    return _init(key_for(seed, 0), frozen, block)
