@@ -25,8 +25,10 @@ class ModelConfig:
     head_dim: int | None = None  # defaults to d_model // n_heads
     norm: str = "rms"  # rms | ln
     norm_bias: bool = False
+    norm_eps: float = 1e-6  # every LayerNorm/RMSNorm of the model, folded ones too
     qk_norm: bool = False
-    pos: str = "rope"  # rope | sincos | none
+    qk_norm_kind: str = "rms"  # rms (γ) | ln (γ, β): one norm over head_dim, shared by heads
+    pos: str = "rope"  # rope | rope2d (patch row/column, vggt) | sincos | none
     rope_theta: float = 10_000.0
     attn_bias: bool = False
     attn_impl: str = "flash"  # flash | two_stage | vanilla (ablation)
@@ -39,7 +41,7 @@ class ModelConfig:
     # kernels.ops.attention_tiles at trace time; None = policy defaults
     attn_tiles: tuple | None = None
     attn_dtype: str = "f32"  # f32 | bf16 streaming-attention compute dtype
-    act: str = "swiglu"  # swiglu | geglu | gelu
+    act: str = "swiglu"  # swiglu | geglu | gelu (tanh) | gelu_erf (exact)
     # --- MoE ---
     moe: bool = False
     n_experts: int = 0
@@ -72,6 +74,9 @@ class ModelConfig:
     # --- vggt ---
     vggt: bool = False
     n_special_tokens: int = 5  # camera + register tokens per frame
+    # 1: every frame takes the same special tokens; 2: frame 0 takes set 0
+    # and frames 1..S-1 set 1 (published VGGT's first-frame tokens)
+    n_special_sets: int = 1
     layerscale: bool = False
     layerscale_init: float = 1e-5
 

@@ -285,7 +285,8 @@ def _norm_u_for(kind: str, dim: int, groups: int | None):
 
 
 def _fuse_qkv(
-    mx: dict, mn_kind: str, d_model: int, groups, rotated: bool, decision=None
+    mx: dict, mn_kind: str, d_model: int, groups, rotated: bool, decision=None,
+    eps: float = 1e-6,
 ) -> dict:
     """Merge prepared wq/wk/wv into one ``wqkv`` site with a norm→quantize
     prologue, and move wo's IDCT/bias epilogue in-kernel.
@@ -311,7 +312,7 @@ def _fuse_qkv(
             isinstance(mx["wo"], QuantLinear)
             and _panel_bytes(mx["wo"], groups) <= FUSED_PANEL_BUDGET
         )
-    pro = Prologue(norm=mn_kind) if rotated else None
+    pro = Prologue(norm=mn_kind, eps=eps) if rotated else None
     mx["wqkv"] = _concat_sites(
         parts,
         prologue=pro,
@@ -328,7 +329,8 @@ def _fuse_qkv(
 
 
 def _fuse_ffn(
-    f: dict, act: str, fn_kind: str, d_model: int, groups, rotated: bool, decision=None
+    f: dict, act: str, fn_kind: str, d_model: int, groups, rotated: bool, decision=None,
+    eps: float = 1e-6,
 ):
     """Prepared dense-FFN dict -> :class:`FusedFFN` (one launch per layer)
     when every member site is quantized compatibly; else unchanged.
@@ -353,8 +355,9 @@ def _fuse_ffn(
         w_down=down,
         w_gate=gate,
         norm_u=_norm_u_for(fn_kind, d_model, groups) if rotated else None,
-        act=gated_act if gate is not None else "gelu",
+        act=gated_act if gate is not None else ("gelu_erf" if act == "gelu_erf" else "gelu"),
         norm=fn_kind if rotated else None,
+        norm_eps=eps,
     )
 
 
@@ -421,8 +424,8 @@ def _quantize_layer(cfg, lp, kind, fk, pol: _Resolver, rotated, *, lead, pfx):
     b2 = fnm.b if rotated else None
     groups = int(mn.g.shape[0]) if lead else None
     if rotated:
-        out["mixer_norm"] = _folded(mn.kind, cfg.d_model, groups)
-        out["ffn_norm"] = _folded(fnm.kind, cfg.d_model, groups)
+        out["mixer_norm"] = _folded(mn.kind, cfg.d_model, groups, mn.eps)
+        out["ffn_norm"] = _folded(fnm.kind, cfg.d_model, groups, fnm.eps)
     ls1 = lp.get("ls1")
     ls2 = lp.get("ls2")
 
@@ -485,7 +488,7 @@ def _quantize_layer(cfg, lp, kind, fk, pol: _Resolver, rotated, *, lead, pfx):
                              rotate_out_offline=rotated)
             if pol.fuse:
                 mx = _fuse_qkv(mx, mn.kind, cfg.d_model, groups, rotated,
-                               decision=pol.fuse_decision(f"{pfx}.mixer.wqkv"))
+                               decision=pol.fuse_decision(f"{pfx}.mixer.wqkv"), eps=mn.eps)
         out["mixer"] = mx
         if ls1 is not None:
             out.pop("ls1", None)
@@ -516,7 +519,7 @@ def _quantize_layer(cfg, lp, kind, fk, pol: _Resolver, rotated, *, lead, pfx):
                             rotate_input_online=True, rotate_out_offline=rotated)
         if pol.fuse:
             f = _fuse_ffn(f, cfg.act, fnm.kind, cfg.d_model, groups, rotated,
-                          decision=pol.fuse_decision(f"{pfx}.ffn"))
+                          decision=pol.fuse_decision(f"{pfx}.ffn"), eps=fnm.eps)
         out["ffn"] = f
         if ls2 is not None:
             out.pop("ls2", None)
@@ -567,9 +570,9 @@ def _bcast(x, n):
     return jnp.broadcast_to(x[..., None, :], x.shape[:-1] + (n, x.shape[-1]))
 
 
-def _folded(kind: str, dim: int, groups: int | None) -> FoldedNorm:
+def _folded(kind: str, dim: int, groups: int | None, eps: float = 1e-6) -> FoldedNorm:
     """FoldedNorm whose LN mean-vector ``u`` is stacked for scan groups."""
-    fn = make_folded_norm(kind, dim)
+    fn = make_folded_norm(kind, dim, eps)
     if fn.u is not None and groups is not None:
         fn = FoldedNorm(kind=fn.kind, u=jnp.broadcast_to(fn.u, (groups, dim)), eps=fn.eps)
     return fn
@@ -584,7 +587,10 @@ def quantize_vggt(cfg: ModelConfig, params: dict, policy) -> dict:
     """Quantize the VGGT tree (models/vggt.py) with a uniform
     ``QuantPolicy`` or a per-site ``PrecisionPlan``: rotated stream via the
     patch projection + rotated special tokens; AA blocks quantized per
-    site with LayerScale folded; heads stay fp with final-norm fold."""
+    site with LayerScale folded; heads stay fp with final-norm fold.  Each
+    norm keeps its own eps; a block's q/k norms (``q_norm``/``k_norm``,
+    over head_dim after the projections) stay float ``Norm``s, and the
+    value bias takes wv's per-head Hadamard with the weight."""
     pol = _Resolver(policy)
     rotated = pol.use_wht
     q = dict(params)
@@ -605,8 +611,8 @@ def quantize_vggt(cfg: ModelConfig, params: dict, policy) -> dict:
         nb = dict(bp)
         groups = int(an.g.shape[0])
         if rotated:
-            nb["attn_norm"] = _folded("ln", cfg.d_model, groups)
-            nb["ffn_norm"] = _folded("ln", cfg.d_model, groups)
+            nb["attn_norm"] = _folded("ln", cfg.d_model, groups, an.eps)
+            nb["ffn_norm"] = _folded("ln", cfg.d_model, groups, fn.eps)
         at = dict(bp["attn"])
         dh = cfg.head_dim
         for name in ("wq", "wk"):
@@ -622,7 +628,7 @@ def quantize_vggt(cfg: ModelConfig, params: dict, policy) -> dict:
                          rotate_out_offline=rotated)
         if pol.fuse:
             at = _fuse_qkv(at, an.kind, cfg.d_model, groups, rotated,
-                           decision=pol.fuse_decision(f"{pfx}.attn.wqkv"))
+                           decision=pol.fuse_decision(f"{pfx}.attn.wqkv"), eps=an.eps)
         nb["attn"] = at
         ff = dict(bp["ffn"])
         for name in ("w_gate", "w_up"):
@@ -635,7 +641,7 @@ def quantize_vggt(cfg: ModelConfig, params: dict, policy) -> dict:
                              rotate_input_online=True, rotate_out_offline=rotated)
         if pol.fuse:
             ff = _fuse_ffn(ff, cfg.act, fn.kind, cfg.d_model, groups, rotated,
-                           decision=pol.fuse_decision(f"{pfx}.ffn"))
+                           decision=pol.fuse_decision(f"{pfx}.ffn"), eps=fn.eps)
         nb["ffn"] = ff
         nb.pop("ls1", None)
         nb.pop("ls2", None)
@@ -648,7 +654,7 @@ def quantize_vggt(cfg: ModelConfig, params: dict, policy) -> dict:
 
     fn: Norm = params["final_norm"]
     if rotated:
-        q["final_norm"] = make_folded_norm("ln", cfg.d_model)
+        q["final_norm"] = make_folded_norm("ln", cfg.d_model, fn.eps)
         for head in ("camera_head", "dpt_head"):
             h = dict(params[head])
             h["fc1"] = _fold_fp(params[head]["fc1"]["w"], gamma=fn.g, beta=fn.b,

@@ -28,6 +28,7 @@ from repro.core import transforms
 from repro.core.quantize import QTensor, quantize_per_token
 from repro.kernels import fused as _fused
 from repro.kernels import probe
+from repro.kernels import qk_rope as _qk
 from repro.kernels import quant_matmul as _qm
 from repro.kernels import two_stage_attention as _tsa
 from repro.kernels import wht as _wht
@@ -36,6 +37,7 @@ from repro.obs import trace as obs_trace
 __all__ = [
     "quant_linear_matmul",
     "two_stage_mha",
+    "qk_norm_rope",
     "online_wht_2d",
     "fused_linear",
     "fused_ffn_apply",
@@ -402,6 +404,46 @@ def two_stage_mha(
         **tile_kw,
     )
     return out[:, :lq].reshape(b, h, lq, dh)
+
+
+def qk_norm_rope(
+    x: jnp.ndarray,
+    cos: jnp.ndarray,
+    sin: jnp.ndarray,
+    norms: jnp.ndarray,
+    *,
+    n_heads: int,
+    head_dim: int,
+    eps: float,
+    role: str | None = None,
+    interpret: bool | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-head LayerNorm → 2D RoPE → per-head WHT of q and k in one launch
+    (``kernels/qk_rope.py``).
+
+    ``x`` [N, L, W] holds q and then k in its first 2·n_heads·head_dim
+    columns: the fused ``wqkv`` output as it is.  L is a whole number of frames of the
+    [T, head_dim] tables' T tokens; token ℓ takes row ℓ mod T.  ``norms``
+    [4, head_dim] holds γ_q, β_q, γ_k, β_k.  Returns
+    float32 q and k [N, L, n_heads·head_dim].  Each trace emits one
+    ``qk_rope.tiles`` event (``obs.trace``) with the block it tiles by:
+    T rows, ``cols`` columns, over ``steps`` grid steps."""
+    interpret = _default_interpret() if interpret is None else interpret
+    n, length, width = x.shape
+    t = cos.shape[0]
+    hd = n_heads * head_dim
+    assert length % t == 0, (length, t)
+    bn = next((b for b in _qk.COL_BLOCKS if hd % b == 0), hd)
+    frames = n * (length // t)
+    obs_trace.emit("qk_rope.tiles", role=role, rows=t, cols=bn, frames=frames,
+                   steps=frames * (hd // bn))
+    probe.record("qk_norm_rope", nbytes=2 * n * length * hd * (x.dtype.itemsize + 4))
+    q, k = _qk.qk_norm_rope(
+        x.reshape(frames, t, width), cos, sin, norms,
+        n_heads=n_heads, head_dim=head_dim, eps=eps,
+        bn=bn, role=role, interpret=interpret,
+    )
+    return q.reshape(n, length, hd), k.reshape(n, length, hd)
 
 
 def online_wht_2d(x: jnp.ndarray, interpret: bool | None = None, **kw) -> jnp.ndarray:

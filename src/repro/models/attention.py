@@ -119,8 +119,10 @@ def init_gqa(key, cfg: ModelConfig, dtype=jnp.float32):
         "wo": L.init_linear(ks[3], cfg.n_heads * dh, cfg.d_model, bias=cfg.attn_bias, dtype=dtype),
     }
     if cfg.qk_norm:
-        p["q_norm"] = L.init_norm(dh, kind="rms", dtype=dtype)
-        p["k_norm"] = L.init_norm(dh, kind="rms", dtype=dtype)
+        kind = cfg.qk_norm_kind
+        for name in ("q_norm", "k_norm"):
+            p[name] = L.init_norm(dh, kind=kind, bias=kind == "ln", dtype=dtype,
+                                  eps=cfg.norm_eps)
     return p
 
 
@@ -273,6 +275,19 @@ def _two_stage_kernel_sdpa(q, k, v, *, causal: bool, tiles: tuple | None = None,
     return jnp.moveaxis(o, 1, 2)
 
 
+def _qk_kernel_path(cfg: ModelConfig) -> bool:
+    """Published VGGT's q/k work (LayerNorm, 2D RoPE, per-head WHT) runs as
+    one Pallas launch on the quantized two-stage path."""
+    return (
+        cfg.pos == "rope2d"
+        and cfg.qk_norm
+        and cfg.qk_norm_kind == "ln"
+        and cfg.n_kv_heads == cfg.n_heads
+        and getattr(cfg, "attn_impl", "flash") == "two_stage"
+        and getattr(cfg, "attn_use_kernel", True)
+    )
+
+
 def gqa_attention(
     p: dict,
     cfg: ModelConfig,
@@ -285,11 +300,16 @@ def gqa_attention(
     kv_mask: Optional[jnp.ndarray] = None,
     pad_lens: Optional[jnp.ndarray] = None,
     role: Optional[str] = None,
+    rope2d: Optional[tuple[jnp.ndarray, jnp.ndarray]] = None,
 ) -> tuple[jnp.ndarray, Optional[KVCache]]:
-    """``role`` is a static label for the two-stage kernel's launch names
-    (``kernels/two_stage_attention.py``); other paths ignore it."""
+    """``role`` is a static label for the two-stage kernel's and the q/k
+    kernel's launch names (``kernels/two_stage_attention.py``,
+    ``kernels/qk_rope.py``); other paths ignore it.  ``rope2d`` is one
+    frame's (cos, sin) [T, head_dim] for ``pos="rope2d"``
+    (``layers.rope2d_table``): the sequence is whole frames of T tokens."""
     b, lq, _ = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    qkv = None
     if "wqkv" in p:
         # unified datapath: one launch runs the absorbed pre-norm (the
         # caller passed the raw stream — see ``core.versaq.carries_norm``),
@@ -305,20 +325,40 @@ def gqa_attention(
         q = L.dense(p["wq"], x).reshape(b, lq, h, dh)
         k = L.dense(p["wk"], x).reshape(b, lq, hkv, dh)
         v = L.dense(p["wv"], x).reshape(b, lq, hkv, dh)
-    if cfg.qk_norm:
-        q = L.norm(p["q_norm"], q)
-        k = L.norm(p["k_norm"], k)
-    if positions is None:
-        positions = jnp.arange(lq)[None, :]
-    if cfg.pos == "rope":
-        cos, sin = L.rope_freqs(dh, cfg.rope_theta, positions)
-        q = L.apply_rope(q, cos, sin)
-        k = L.apply_rope(k, cos, sin)
-    if quantized:
-        # paper Stage 2: post-RoPE online per-head WHT (scores invariant)
-        q = head_wht(q)
-        k = head_wht(k)
-        # V arrives per-head-rotated from the offline W_v fusion.
+    if cfg.pos == "rope2d" and rope2d is None:
+        raise ValueError("pos='rope2d' needs the frame's (cos, sin) tables: rope2d=")
+    if quantized and _qk_kernel_path(cfg):
+        # q/k norm -> 2D RoPE -> per-head WHT in one launch, read straight
+        # from the fused site's output (kernels/qk_rope.py)
+        from repro.kernels import ops as kernel_ops
+
+        if qkv is None:
+            qkv = jnp.concatenate([q.reshape(b, lq, -1), k.reshape(b, lq, -1)], axis=-1)
+        qn, kn = p["q_norm"], p["k_norm"]
+        q, k = kernel_ops.qk_norm_rope(
+            qkv, *rope2d, jnp.stack([qn.g, qn.b, kn.g, kn.b]),
+            n_heads=h, head_dim=dh, eps=qn.eps, role=role,
+        )
+        q = q.reshape(b, lq, h, dh)
+        k = k.reshape(b, lq, hkv, dh)
+    else:
+        if cfg.qk_norm:
+            q = L.norm(p["q_norm"], q)
+            k = L.norm(p["k_norm"], k)
+        if positions is None:
+            positions = jnp.arange(lq)[None, :]
+        if cfg.pos == "rope":
+            cos, sin = L.rope_freqs(dh, cfg.rope_theta, positions)
+            q = L.apply_rope(q, cos, sin)
+            k = L.apply_rope(k, cos, sin)
+        elif cfg.pos == "rope2d":
+            q = L.apply_rope2d(q, *rope2d)
+            k = L.apply_rope2d(k, *rope2d)
+        if quantized:
+            # paper Stage 2: post-RoPE online per-head WHT (scores invariant)
+            q = head_wht(q)
+            k = head_wht(k)
+    # V arrives per-head-rotated from the offline W_v fusion.
 
     if pad_lens is not None:
         # left-padded serving buckets: derive the key mask; exclusive with

@@ -43,7 +43,7 @@ def dense_ffn(p: dict, act: str, x: jnp.ndarray) -> jnp.ndarray:
         u = L.dense(p["w_up"], x)
         h = (L.silu(g) if act == "swiglu" else L.gelu(g)) * u
         return L.dense(p["w_down"], h)
-    h = L.gelu(L.dense(p["w_up"], x))
+    h = (L.gelu_erf if act == "gelu_erf" else L.gelu)(L.dense(p["w_up"], x))
     return L.dense(p["w_down"], h)
 
 

@@ -7,9 +7,10 @@ one forward pass per scene, under tight latency budgets.  The naive
 this engine is the production version:
 
 * **Shape buckets** — requests are keyed on ``(n_frames, n_patches,
-  batch)``; the batch dim is padded up to a bucket size (powers of two by
-  default) and each bucket holds exactly one jitted forward, so repeated
-  traffic never recompiles.  With ``pad_patches=True`` the patch dim is
+  batch)``, and for a 2D-RoPE model (``pos="rope2d"``) on the frames'
+  patch grid too; the batch dim is padded up to a bucket size (powers of
+  two by default) and each bucket holds exactly one jitted forward, so
+  repeated traffic never recompiles.  With ``pad_patches=True`` the patch dim is
   also rounded up (per-frame padding, masked out of every attention
   softmax via ``vggt.forward(patch_mask=...)``) which lets scenes with
   different patch counts share buckets and micro-batches.
@@ -60,15 +61,25 @@ DEFAULT_BATCH_BUCKETS = (1, 2, 4, 8, 16)
 @dataclasses.dataclass(frozen=True)
 class Bucket(batching.Bucket):
     """One compiled shape: batch is padded up, frames exact, patches
-    padded only with ``pad_patches``; per precision tier.  Prints as
-    ``b4xs2xp24`` (``fast:b4xs2xp24`` for a non-default tier)."""
+    padded only with ``pad_patches``; per precision tier and, for a
+    2D-RoPE model, per patch grid.  Prints as ``b4xs2xp24``
+    (``fast:b4xs2xp24`` for a non-default tier, ``b1xs8xp1369xg37x37``
+    with a grid)."""
 
     batch: int
     frames: int
     patches: int
     tier: str = "default"
+    grid: Optional[tuple[int, int]] = None  # (rows, cols): identity, not an axis
 
     AXES = ("b", "s", "p")
+
+    def sizes(self) -> tuple:
+        return tuple(v for v in super().sizes() if v is not self.grid)
+
+    def __str__(self) -> str:
+        s = super().__str__()
+        return s if self.grid is None else f"{s}xg{self.grid[0]}x{self.grid[1]}"
 
 
 class VGGTServeStats(batching.ServeStats):
@@ -88,6 +99,7 @@ class PendingRequest(batching.PendingRequest):
     scenes: jnp.ndarray  # [b, S, P, d]
     n_patches: int  # real (unpadded) patch count
     tier: str = "default"  # precision tier (engine ``tiers`` key)
+    grid: Optional[tuple[int, int]] = None  # patch grid (rows, cols), 2D-RoPE models
 
 
 class VGGTEngine:
@@ -203,11 +215,11 @@ class VGGTEngine:
     # ---- buckets ---------------------------------------------------------
 
     def bucket_for(
-        self, batch: int, frames: int, patches: int, tier: str = "default"
+        self, batch: int, frames: int, patches: int, tier: str = "default", grid=None
     ) -> Bucket:
         b = pick_bucket(self.batch_buckets, batch)
         p = next_pow2(patches) if self.pad_patches else patches
-        return Bucket(batch=b, frames=frames, patches=p, tier=tier)
+        return Bucket(batch=b, frames=frames, patches=p, tier=tier, grid=grid)
 
     def _bucket_fn(self, bucket: Bucket, masked: bool):
         """The bucket's jitted forward; cache miss == one compile.
@@ -221,26 +233,27 @@ class VGGTEngine:
             self.stats.bucket(bucket).compiles += 1
             if masked:
                 fn = jax.jit(
-                    lambda p, x, m: vggt_mod.forward(self.cfg, p, x, patch_mask=m)
+                    lambda p, x, m: vggt_mod.forward(self.cfg, p, x, patch_mask=m,
+                                                     grid=bucket.grid)
                 )
             else:
-                fn = jax.jit(functools.partial(vggt_mod.forward, self.cfg))
+                fn = jax.jit(functools.partial(vggt_mod.forward, self.cfg, grid=bucket.grid))
             self._fns[key] = fn
         return fn
 
     # ---- request path ----------------------------------------------------
 
-    def _group_key(self, scenes: jnp.ndarray, tier: str) -> tuple[str, int, int]:
+    def _group_key(self, scenes: jnp.ndarray, tier: str, grid=None) -> tuple:
         s, p_ = scenes.shape[1], scenes.shape[2]
-        return (tier, s, next_pow2(p_) if self.pad_patches else p_)
+        return (tier, s, next_pow2(p_) if self.pad_patches else p_, grid)
 
-    def infer(self, scenes: jnp.ndarray, tier: Optional[str] = None) -> dict:
+    def infer(self, scenes: jnp.ndarray, tier: Optional[str] = None, *, grid=None) -> dict:
         """Serve one request synchronously (still bucket-padded/cached).
         Flushes only this request's group — pending micro-batches of
         other shapes/tiers keep coalescing."""
-        req = self.enqueue(scenes, tier=tier)
+        req = self.enqueue(scenes, tier=tier, grid=grid)
         if not req.ready:
-            self._queue.flush_group(self._group_key(req.scenes, req.tier))
+            self._queue.flush_group(self._group_key(req.scenes, req.tier, req.grid))
         return req.result()
 
     def enqueue(
@@ -250,10 +263,15 @@ class VGGTEngine:
         *,
         priority: int = 0,
         deadline_s: Optional[float] = None,
+        grid: Optional[tuple[int, int]] = None,
     ) -> PendingRequest:
         """Queue a [b, S, P, d] scene batch; auto-flushes a group the
         moment it reaches ``max_batch`` scenes.  ``tier`` selects the
         precision tier; requests only coalesce within their tier.
+        ``grid`` = (rows, cols) places the frames' patches for a 2D-RoPE
+        model: it defaults to the square grid where P is a perfect square,
+        and a request with neither is refused (``ValueError``); requests
+        coalesce only within their grid.  Other models ignore it.
         Higher ``priority`` requests are packed into a flushing
         micro-batch first; a request older than ``deadline_s`` seconds is
         evicted (its ``result()`` raises ``DeadlineExceeded``) instead of
@@ -281,6 +299,7 @@ class VGGTEngine:
         req = PendingRequest(
             scenes=scenes, n_patches=p_, tier=tier,
             priority=priority, deadline_s=deadline_s,
+            grid=vggt_mod.patch_grid(self.cfg, p_, grid),
         )
         if self._admission.bounded:
             try:
@@ -303,7 +322,7 @@ class VGGTEngine:
             "enqueue", request=req.req_id, kind="vggt", tier=tier,
             scenes=b, frames=scenes.shape[1], patches=p_, priority=priority,
         )
-        self._queue.add(self._group_key(scenes, tier), req, b)
+        self._queue.add(self._group_key(scenes, tier, req.grid), req, b)
         return req
 
     @property
@@ -368,13 +387,14 @@ class VGGTEngine:
 
     # ---- micro-batch execution -------------------------------------------
 
-    def _run(self, key: tuple[str, int, int], reqs: list[PendingRequest]) -> None:
+    def _run(self, key: tuple, reqs: list[PendingRequest]) -> None:
         """Serve one micro-batch.  Besides the per-request ``admit`` /
         ``forward`` events, each host phase around the forward is a span
         (``vggt.assemble``, ``vggt.check``, ``vggt.deliver``) with one
         batch-level event carrying this engine's ``forward`` ordinal, the
-        ``requests`` served and the ``bucket``."""
-        tier, frames, p_bucket = key
+        ``requests`` served and the ``bucket`` (``vggt.assemble`` also the
+        patch ``grid``, ``rows x cols``, or None)."""
+        tier, frames, p_bucket, grid = key
         for r in reqs:
             obs_trace.emit(
                 "admit", request=r.req_id, tier=tier, frames=frames,
@@ -385,8 +405,9 @@ class VGGTEngine:
         with obs_trace.span("vggt.assemble", **batch) as labels:
             params = self.tier_params(tier)
             n_real = sum(r.scenes.shape[0] for r in reqs)
-            bucket = self.bucket_for(n_real, frames, p_bucket, tier)
+            bucket = self.bucket_for(n_real, frames, p_bucket, tier, grid)
             labels["bucket"] = batch["bucket"] = str(bucket)
+            labels["grid"] = None if grid is None else f"{grid[0]}x{grid[1]}"
             d = reqs[0].scenes.shape[-1]
             dtype = reqs[0].scenes.dtype
 

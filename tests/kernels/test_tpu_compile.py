@@ -183,3 +183,40 @@ def test_vggt_forward_names_attention_launches_by_role(one_chip, monkeypatch):
     assert {"fused_matmul", "fused_ffn"} <= launches
     assert 'op_name="jit(<lambda>)/while/body/closed_call/frame/' in text
     assert 'op_name="jit(<lambda>)/while/body/closed_call/global/' in text
+
+
+@pytest.mark.parametrize("frames", [8, 16])
+def test_qk_norm_rope_compiles(one_chip, frames):
+    """Published VGGT's q/k LayerNorm → 2D RoPE → per-head WHT, reading q
+    and k from the fused wqkv output of S frames of 1,374 tokens."""
+    qkv = _spec(one_chip, (1, frames * FRAME_TOKENS, 3 * 1024))
+    table = _spec(one_chip, (FRAME_TOKENS, 64))
+    _compile(
+        lambda x, c, s, n: ops.qk_norm_rope(x, c, s, n, n_heads=16, head_dim=64, eps=1e-5,
+                                            role="global", interpret=False),
+        qkv, table, table, _spec(one_chip, (4, 64)),
+    )
+
+
+def test_published_forward_names_qk_launches_by_role(one_chip, monkeypatch):
+    """A whole W4A8 forward of VGGT-1B as published (one AA pair, two
+    frames) compiled for the chip: the q/k kernel runs in both blocks under
+    its role's name, beside the two-stage launches, and the fused FFN's
+    exact-GELU epilogue lowers."""
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = get_config("vggt-1b-published").with_(n_layers=1, attn_impl="two_stage")
+    plan = ServeSpec.parse("w4a8:fused").materialize()
+    tree = jax.eval_shape(
+        lambda: quantize_vggt(cfg, vggt.init_params(cfg, jax.random.PRNGKey(0)), plan)
+    )
+    assert tree["blocks"]["global"]["ffn"].act == "gelu_erf"
+    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+    text = _compile(
+        lambda p, x: vggt.forward(cfg, p, x), params, _spec(one_chip, (1, 2, 1369, 1024))
+    )
+    launches = {
+        re.sub(r"\.\d+$", "", m)
+        for m in re.findall(r"%?([\w.]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    }
+    assert {"qk_norm_rope_frame", "qk_norm_rope_global"} <= launches
+    assert {"two_stage_attention_global_stats", "fused_ffn"} <= launches

@@ -50,6 +50,12 @@ def served():
     """One request served through an AsyncServer with a live metrics
     surface on an ephemeral port."""
     cfg, params = _fixture()
+    # the latency histogram lives in the process-wide registry: start it
+    # empty, so deliveries of servers that ran earlier in this process
+    # do not count here
+    hist = obs_metrics.default().get("serve_request_latency_seconds")
+    if hist is not None:
+        hist.clear()
     eng = Engine(cfg, params, max_len=32, mode="continuous", max_wait_s=0.0)
     srv = AsyncServer(eng, metrics_port=0)
     try:

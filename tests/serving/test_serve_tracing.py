@@ -203,3 +203,18 @@ def test_plain_policy_sites_survive_quantization(tracer):
         assert quant_health.sites_sampled()
     finally:
         quant_health.disable()
+
+
+def test_vggt_assemble_span_carries_the_patch_grid(tracer):
+    """``vggt.assemble`` labels each forward with the frames' patch grid:
+    rows x cols for a 2D-RoPE model, None for the reduced one."""
+    from repro.data.pipeline import scene_batch
+
+    pub = get_config("vggt-1b-published-smoke").with_(n_layers=1)
+    for cfg, params, grid in ((pub, vggt.init_params(pub, jax.random.PRNGKey(0)), "2x8"),
+                              (*_vggt_fixture(), None)):
+        eng = VGGTEngine(cfg, params, max_batch=1)
+        x = jax.numpy.asarray(scene_batch(1, 2, 16, cfg.d_model, 0)["patches"])
+        eng.infer(x, grid=(2, 8))
+        (ev,) = [e for e in tracer.recent() if e.phase == "vggt.assemble"][-1:]
+        assert ev.labels["grid"] == grid

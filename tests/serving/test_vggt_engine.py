@@ -173,3 +173,56 @@ def test_two_stage_kernel_close_to_quantized_flash():
     rel = float(jnp.linalg.norm(a["points"] - b["points"])
                 / jnp.linalg.norm(a["points"]))
     assert rel < 0.15, rel
+
+
+@functools.lru_cache(maxsize=1)
+def _published_fixture():
+    cfg = get_config("vggt-1b-published-smoke").with_(n_layers=1, layerscale_init=0.2)
+    return cfg, vggt.init_params(cfg, KEY)
+
+
+def _published_scenes(patches, seed):
+    cfg, _ = _published_fixture()
+    return jnp.asarray(scene_batch(1, 2, patches, cfg.d_model, seed)["patches"])
+
+
+def test_patch_grid_is_part_of_the_bucket_for_2d_rope():
+    """A 2D-RoPE model keys buckets (and micro-batch groups) on the patch
+    grid: the square default and an explicit 2 x 8 grid of the same 16
+    patches are two compiles with different answers; the reduced model's
+    buckets carry no grid."""
+    cfg, params = _published_fixture()
+    eng = VGGTEngine(cfg, params, max_batch=4)
+    x = _published_scenes(16, seed=30)
+    square = eng.infer(x)
+    wide = eng.infer(x, grid=(2, 8))
+    assert set(eng.stats.buckets) == {Bucket(1, 2, 16, grid=(4, 4)), Bucket(1, 2, 16, grid=(2, 8))}
+    assert str(Bucket(1, 2, 16, grid=(4, 4))) == "b1xs2xp16xg4x4"
+    assert eng.stats.compiles == 2
+    assert float(jnp.max(jnp.abs(square["points"] - wide["points"]))) > 1e-4
+    np.testing.assert_allclose(square["points"], vggt.forward(cfg, params, x)["points"],
+                               rtol=1e-5, atol=1e-5)
+    a = eng.enqueue(x)
+    b = eng.enqueue(x, grid=(2, 8))
+    assert not a.ready and not b.ready and eng.pending == 2  # separate groups
+    eng.flush()
+    assert eng.stats.calls == 4
+    red_cfg, red_params = _fixture()
+    red = VGGTEngine(red_cfg, red_params, batch_buckets=(2,))
+    red.infer(_scenes(2, seed=31), grid=(4, 6))
+    assert set(red.stats.buckets) == {Bucket(2, 2, 24)}
+
+
+def test_2d_rope_request_without_a_grid_is_refused():
+    """24 patches a frame are no square: a 2D-RoPE model refuses the
+    request unless it names its grid."""
+    cfg, params = _published_fixture()
+    eng = VGGTEngine(cfg, params)
+    x = _published_scenes(24, seed=32)
+    with pytest.raises(ValueError, match="grid"):
+        eng.enqueue(x)
+    with pytest.raises(ValueError, match="grid"):
+        eng.enqueue(x, grid=(5, 5))  # more patches than the frame has
+    assert eng.pending == 0
+    out = eng.infer(x, grid=(4, 6))
+    assert out["points"].shape == (1, 2, 24, 3)
