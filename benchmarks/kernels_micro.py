@@ -22,7 +22,7 @@ def main():
         hbm = x.size + wq.values.size + 64 * 128 * 4
         common.emit(f"kernels.quant_matmul.w{bits}", us, f"hbm_bytes={hbm} (w4 halves weight traffic)")
     q = jnp.asarray(RNG.normal(size=(1, 2, 128, 64)), jnp.float32)
-    us = common.timeit(lambda: ops.two_stage_mha(q, q, q, causal=False, bq=64, bk=64, bkv=128))
+    us = common.timeit(lambda: ops.two_stage_mha(q, q, q, causal=False, bq=64, bk=128, bkv=128))
     m = vmem_bytes_two_stage(64, 64, 2048, 64)
     common.emit("kernels.two_stage_mha", us,
                 f"vmem_stage1={m['stage1']}B vmem_stage2={m['stage2']}B vs_flash={m['flash_same_tiles']}B")

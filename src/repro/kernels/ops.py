@@ -320,13 +320,27 @@ def matmul_traffic_bytes(
     return x_bytes + w_bytes + out_bytes + scale_bytes
 
 
+def _scale_row_bytes(bh: int, nq: int, nk: int, bq: int, bk: int) -> int:
+    """Bytes of the f32 scale rows one two-stage launch DMAs over its
+    (bh, nq, nk) grid: three query-side rows of ``bq`` per query tile
+    (q scale in; ``M``, ``Σ`` out of stage ①, in to stage ②) and the key
+    scale row of ``bk``, fetched every step while the key axis moves and
+    once a head when it is one tile (a block is re-read only when its
+    index changes)."""
+    key_fetches = bh * nq * nk if nk > 1 else bh
+    return 4 * (3 * bh * nq * bq + key_fetches * bk)
+
+
 def _attention_traffic_bytes(bh: int, lqp: int, lkp: int, dh: int, tiles: dict) -> int:
     """Modeled bytes for the two-stage attention pair of launches."""
     bq, bk, bkv = tiles["bq"], tiles["bk"], tiles["bkv"]
+    nq = lqp // bq
     # stage ① (stats): Q re-streams per K tile, K per Q tile
-    s1 = bh * (lqp * dh * (lkp // bk) + lkp * dh * (lqp // bq) + lqp * 8)
+    s1 = bh * (lqp * dh * (lkp // bk) + lkp * dh * nq)
+    s1 += _scale_row_bytes(bh, nq, lkp // bk, bq, bk)
     # stage ② (PV): Q/V re-stream against the coarser T_V tiling
-    s2 = bh * (lqp * dh * (lkp // bkv) + lkp * dh * (lqp // bq) + lqp * dh * 4)
+    s2 = bh * (lqp * dh * (lkp // bkv) + lkp * dh * nq + lqp * dh * 4)
+    s2 += _scale_row_bytes(bh, nq, lkp // bkv, bq, bkv)
     return s1 + s2
 
 
@@ -355,7 +369,10 @@ def two_stage_mha(
     via ``kv_len``.  Explicitly passed tiles must divide exactly (legacy
     behavior).  ``role`` names the two launches (``two_stage_attention``'s
     ``role``).  Each trace emits one ``attn.tiles`` event (``obs.trace``)
-    with the resolved tiles, padded lengths and stage ①'s grid steps.
+    with the resolved tiles, padded lengths, stage ①'s grid steps, the
+    stage ② steps whose body applies a mask (the causal ones; padded keys
+    need none there, because K and V are padded with zeros) and the scale
+    bytes one stage ② step DMAs, averaged over its grid.
     """
     interpret = _default_interpret() if interpret is None else interpret
     b, h, lq, dh = q.shape
@@ -363,9 +380,15 @@ def two_stage_mha(
     assert h % hkv == 0, (h, hkv)
 
     tile_kw, lqp, lkp = attention_tiles(lq, lk, **tile_kw)
+    # the kernel clamps each tile to its axis
+    bq, bk, bkv = min(tile_kw["bq"], lqp), min(tile_kw["bk"], lkp), min(tile_kw["bkv"], lkp)
+    nq, nkv = lqp // bq, lkp // bkv
+    stage2_steps = b * h * nq * nkv
     obs_trace.emit(
         "attn.tiles", role=role, **tile_kw, lqp=lqp, lkp=lkp,
-        stage1_steps=b * h * (lqp // tile_kw["bq"]) * (lkp // tile_kw["bk"]),
+        stage1_steps=b * h * nq * (lkp // bk),
+        stage2_masked_steps=stage2_steps if causal else 0,
+        scale_bytes_per_step=_scale_row_bytes(b * h, nq, nkv, bq, bkv) // stage2_steps,
     )
 
     qf = q.reshape(b * h, lq, dh)
@@ -386,7 +409,8 @@ def two_stage_mha(
     vscale_q = jnp.repeat(vscale.reshape(b, hkv, 1, 1), h // hkv, axis=1)
     # stage ① + stage ② launches
     probe.record(
-        "two_stage_mha", 2, nbytes=_attention_traffic_bytes(b * h, lqp, lkp, dh, tile_kw)
+        "two_stage_mha", 2,
+        nbytes=_attention_traffic_bytes(b * h, lqp, lkp, dh, dict(bq=bq, bk=bk, bkv=bkv)),
     )
     out = _tsa.two_stage_attention(
         qq.values,

@@ -4,6 +4,10 @@ Each kernel's test sweeps shapes/dtypes and asserts allclose against these.
 """
 from __future__ import annotations
 
+import functools
+import math
+
+import jax
 import jax.numpy as jnp
 
 from repro.core import transforms
@@ -43,6 +47,7 @@ def attention_ref(q, k, v, *, causal: bool, scale: float | None = None):
     return jnp.einsum("...qk,...kd->...qd", p, v.astype(jnp.float32))
 
 
+@functools.partial(jax.jit, static_argnames=("causal", "scale"))
 def two_stage_attention_ref(
     qv, qs, kv, ks, vv, vs, *, causal: bool, scale: float | None = None
 ):
@@ -51,16 +56,22 @@ def two_stage_attention_ref(
 
     qv/kv/vv: [..., L, dh] int8; qs/ks: per-token scales [..., L, 1] f32;
     vs: per-tensor (per-head) scalar scale.
+
+    Jitted, so that XLA fuses the score epilogue into one loop as it does
+    the kernel's body in interpret mode: a CPU fusion contracts
+    ``s·k − M`` into one fused multiply-add, which rounds once where the
+    op-by-op form rounds twice and can flip ⌊127·exp(s−M)⌉.
     """
     dh = qv.shape[-1]
-    scale = scale if scale is not None else 1.0 / jnp.sqrt(jnp.float32(dh))
-    # integer-exact dot first, scales after — the kernel's exact op order
-    # (int8 products/sums are exact in f32; pre-scaling would introduce
-    # rounding that flips ⌊127·exp(s−M)⌉ at boundaries)
+    scale = scale if scale is not None else 1.0 / (dh**0.5)
+    # integer-exact dot first, scales after, in the kernel's exact op order:
+    # the q scale with the softmax scale folded in, then the key scale
+    # (int8 products/sums are exact in f32; any other order rounds
+    # differently and flips ⌊127·exp(s−M)⌉ at boundaries)
     s_int = jnp.einsum(
         "...qd,...kd->...qk", qv.astype(jnp.float32), kv.astype(jnp.float32)
     )
-    s = s_int * qs * jnp.swapaxes(ks, -1, -2) * scale
+    s = s_int * (qs * scale) * jnp.swapaxes(ks, -1, -2)
     if causal:
         lq, lk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((lq, lk), bool), k=lk - lq)
@@ -70,8 +81,9 @@ def two_stage_attention_ref(
     l = p.sum(-1, keepdims=True)
     # Alg.1 line 11: quant(S) to int8 with the optimal per-row scale —
     # exp(s−M) has row max 1 so ⌊127·exp(s−M)⌉ spans the full range; the
-    # 1/Σ normalization folds into the output scale.
-    pq = jnp.round(p * 127.0)
+    # 1/Σ normalization folds into the output scale.  As in the kernel,
+    # the factor 127 enters through the exponent: exp(s − (M − ln 127)).
+    pq = jnp.round(jnp.exp(s - (m - math.log(127.0))))
     o = jnp.einsum("...qk,...kd->...qd", pq, vv.astype(jnp.float32))
     return o * (vs / 127.0) / l
 

@@ -158,21 +158,67 @@ def test_packed_w4_quant_matmul_compiles(one_chip, m):
     )
 
 
-def test_vggt_forward_names_attention_launches_by_role(one_chip, monkeypatch):
+# A per-token scalar as a [·, L, 1] f32 column: tiled (8, 128), each
+# scalar fills a 128-lane row, and a block of it moves 128× its bytes.
+SCALE_COLUMN = re.compile(r"f32\[\d+,\d+,1\]")
+COLUMN_COPY = re.compile(r"= f32\[\d+,\d+,1\]\{2,1,0:T\(8,128\)[^}]*\} copy\(")
+
+
+def _assert_lane_dense_scales(text: str) -> None:
+    """No two-stage launch takes or returns a [·, L, 1] scale column, and
+    no copy lays one out."""
+    launches = [
+        line for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+        and re.match(r"\s*%two_stage_attention\w*", line)
+    ]
+    assert len(launches) >= 2
+    for line in launches:
+        assert not SCALE_COLUMN.search(line), line[:300]
+    assert not COLUMN_COPY.search(text)
+
+
+@pytest.mark.parametrize("frames", [8, 16])
+def test_two_stage_scales_are_lane_dense(one_chip, frames):
+    """Global attention over an S-frame scene: q/k scales, M and Σ reach
+    and leave both launches as lane-dense rows."""
+    qkv = _spec(one_chip, (1, 16, frames * FRAME_TOKENS, 64))
+    _assert_lane_dense_scales(_compile(
+        lambda q, k, v: ops.two_stage_mha(q, k, v, role="global", interpret=False),
+        qkv, qkv, qkv,
+    ))
+
+
+@pytest.fixture(scope="module")
+def w4a8_pair_forward(one_chip) -> str:
+    """The compiled HLO of a whole W4A8 VGGT-1B forward (one AA pair, two
+    frames) on the described chip."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_default_interpret", lambda: False)
+        cfg = get_config("vggt-1b").with_(n_layers=1, attn_impl="two_stage")
+        plan = ServeSpec.parse("w4a8:fused").materialize()
+        tree = jax.eval_shape(
+            lambda: quantize_vggt(cfg, vggt.init_params(cfg, jax.random.PRNGKey(0)), plan)
+        )
+        params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+        return _compile(
+            lambda p, x: vggt.forward(cfg, p, x), params,
+            _spec(one_chip, (1, 2, 1369, 1024)),
+        )
+
+
+def test_vggt_forward_scales_are_lane_dense(w4a8_pair_forward):
+    """Both roles' launches in a whole forward: no scale column and no
+    relayout copy into one."""
+    _assert_lane_dense_scales(w4a8_pair_forward)
+
+
+def test_vggt_forward_names_attention_launches_by_role(w4a8_pair_forward):
     """A whole W4A8 VGGT-1B forward (one AA pair, two frames) compiled for
     the chip: each block's two-stage launches carry its role and stage,
     no launch keeps the bare ``two_stage_attention`` name, and the fused
     kernels keep theirs."""
-    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
-    cfg = get_config("vggt-1b").with_(n_layers=1, attn_impl="two_stage")
-    plan = ServeSpec.parse("w4a8:fused").materialize()
-    tree = jax.eval_shape(
-        lambda: quantize_vggt(cfg, vggt.init_params(cfg, jax.random.PRNGKey(0)), plan)
-    )
-    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), tree)
-    text = _compile(
-        lambda p, x: vggt.forward(cfg, p, x), params, _spec(one_chip, (1, 2, 1369, 1024))
-    )
+    text = w4a8_pair_forward
     launches = {
         re.sub(r"\.\d+$", "", m)
         for m in re.findall(r"%?([\w.]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)

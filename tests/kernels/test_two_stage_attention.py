@@ -321,8 +321,12 @@ def test_attn_tiles_event_once_per_compile():
     events = [e for e in tracer.recent() if e.phase == "attn.tiles"]
     tiles, lqp, lkp = ops.attention_tiles(l, l)
     steps = b * h * (lqp // tiles["bq"]) * (lkp // tiles["bk"])
+    # 300 tokens pad to one 384 tile on each axis: a stage ② step reads the
+    # q scale, M and Σ rows and the key scale row, 4 B an entry
+    assert lqp == lkp == tiles["bq"] == tiles["bkv"] == 384
     assert [e.labels for e in events] == [
-        {"role": "global", **tiles, "lqp": lqp, "lkp": lkp, "stage1_steps": steps}
+        {"role": "global", **tiles, "lqp": lqp, "lkp": lkp, "stage1_steps": steps,
+         "stage2_masked_steps": 0, "scale_bytes_per_step": 4 * (3 * 384 + 384)}
     ]
 
 
@@ -352,3 +356,118 @@ def test_vggt_forward_emits_attn_tiles_per_role():
         ("frame", ops.attention_tiles(t, t)[1]),
         ("global", ops.attention_tiles(3 * t, 3 * t)[1]),
     ]
+
+
+# ---------------------------------------------------------------------------
+# Padded keys: stage ① masks them out of (M, Σ); stage ② does not mask
+# them, because two_stage_mha pads V with zero rows, so their rounded
+# probabilities add nothing to P·V.
+# ---------------------------------------------------------------------------
+
+
+def _padded_operands(q, k, v, lp):
+    """Quantize [B, H, L, dh] float q/k/v as ``ops.two_stage_mha`` does,
+    after zero-padding the token axis to ``lp``: int8 values, per-token
+    q/k scales, a per-head V scale.  Returns the kernel's padded operands
+    and the oracle's real-length ones, per query head."""
+    b, h, l, dh = q.shape
+    hkv = k.shape[1]
+
+    def pad(x):
+        return jnp.pad(x.reshape(-1, l, dh), ((0, 0), (0, lp - l), (0, 0)))
+
+    qq, kq = quantize_per_token(pad(q), 8), quantize_per_token(pad(k), 8)
+    vf = pad(v)
+    vs = jnp.max(jnp.abs(vf), axis=(1, 2), keepdims=True) / 127.0
+    vv = jnp.clip(jnp.round(vf / vs), -127, 127).astype(jnp.int8)
+    g = h // hkv
+    vs_q = jnp.repeat(vs.reshape(b, hkv, 1, 1), g, axis=1).reshape(b * h, 1, 1)
+    kernel = (qq.values, qq.scale, kq.values, kq.scale, vv, vs_q)
+
+    def per_q_head(x):  # [B·Hkv, lp, c] -> [B·H, l, c], the real tokens
+        x = jnp.repeat(x.reshape(b, hkv, lp, -1), g, axis=1)
+        return x.reshape(b * h, lp, -1)[:, :l]
+
+    oracle = (qq.values[:, :l], qq.scale[:, :l], per_q_head(kq.values),
+              per_q_head(kq.scale), per_q_head(vv), vs_q)
+    return kernel, oracle
+
+
+@pytest.mark.parametrize(
+    "causal,h,hkv,l,lp,bq,bk,bkv",
+    [
+        (False, 2, 2, 1374, 1408, 352, 1408, 1408),
+        (False, 2, 2, 300, 384, 128, 128, 128),
+        (True, 2, 2, 300, 384, 128, 128, 128),
+        (False, 4, 2, 300, 384, 128, 128, 384),
+        (True, 4, 1, 300, 384, 64, 128, 128),
+    ],
+    ids=["frame-one-padded-tile", "last-of-three-tiles", "causal", "gqa-one-stage2-tile",
+         "gqa-causal"],
+)
+def test_padded_keys_vs_int_oracle(causal, h, hkv, l, lp, bq, bk, bkv):
+    """Lane-padded keys, at a frame's length (1,374 → 1,408: its only key
+    tile is padded) and where the padding sits in the last of several key
+    tiles, causal and GQA: exact against the int oracle on the real
+    tokens."""
+    b, dh = 1, 64
+    q = jnp.asarray(RNG.normal(size=(b, h, l, dh)), jnp.float32)
+    k = jnp.asarray(RNG.normal(size=(b, hkv, l, dh)), jnp.float32)
+    v = jnp.asarray(RNG.normal(size=(b, hkv, l, dh)), jnp.float32)
+    kernel, oracle = _padded_operands(q, k, v, lp)
+    gqa = dict(q_heads=h, kv_heads=hkv) if hkv != h else {}
+    got = two_stage_attention(
+        *kernel, causal=causal, bq=bq, bk=bk, bkv=bkv, kv_len=l, interpret=True, **gqa
+    )
+    want = ref.two_stage_attention_ref(*oracle, causal=causal)
+    np.testing.assert_allclose(got[:, :l], want, rtol=3e-4, atol=3e-4)
+
+
+@pytest.mark.parametrize("lp,bq,bk,bkv", [(384, 128, 128, 128), (384, 384, 384, 384)])
+def test_padded_keys_with_scores_far_below_zero(lp, bq, bk, bkv):
+    """A row whose real scores all lie near −200: a padded key scores
+    exactly 0, far above that row's M, so its 127·exp(0 − M) overflows —
+    and multiplies a zero V row.  The output stays finite and exact."""
+    b, h, l, dh = 1, 2, 300, 64
+    q = jnp.asarray(RNG.normal(size=(b, h, l, dh)), jnp.float32)
+    k = jnp.asarray(RNG.normal(size=(b, h, l, dh)) * 0.3 - 5.0, jnp.float32)
+    v = jnp.asarray(RNG.normal(size=(b, h, l, dh)), jnp.float32)
+    q = q.at[:, :, 0].set(5.0 + 0.3 * q[:, :, 0])  # q·k/8 ≈ 5·(−5)·64/8
+    kernel, oracle = _padded_operands(q, k, v, lp)
+    qs_, ks_ = oracle[1], oracle[3]
+    s0 = (oracle[0][:, :1].astype(jnp.float32) @ jnp.swapaxes(oracle[2], 1, 2).astype(
+        jnp.float32)) * qs_[:, :1] * jnp.swapaxes(ks_, 1, 2) / 8.0
+    assert float(s0.max()) < -150 and float(s0.min()) > -250, (s0.min(), s0.max())
+    got = two_stage_attention(*kernel, bq=bq, bk=bk, bkv=bkv, kv_len=l, interpret=True)
+    assert bool(jnp.isfinite(got).all())
+    want = ref.two_stage_attention_ref(*oracle, causal=False)
+    np.testing.assert_allclose(got[:, :l], want, rtol=3e-4, atol=3e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_attn_tiles_event_counts_masked_stage2_steps(causal):
+    """``attn.tiles`` counts the stage ② steps whose body masks: none for
+    a non-causal call on a padded length (padded keys meet zero V rows),
+    every step of a causal one.  At a global S=8 scene the scale rows a
+    stage ② step reads stay under 0.1 MB."""
+    tracer = obs_trace.Tracer()
+    prev = obs_trace.install(tracer)
+    try:
+        for length in (300, 8 * 1374):
+            x = jax.ShapeDtypeStruct((1, 16, length, 64), jnp.float32)
+            jax.eval_shape(
+                lambda q, k, v: ops.two_stage_mha(q, k, v, causal=causal), x, x, x
+            )
+    finally:
+        obs_trace.install(prev)
+    events = [e.labels for e in tracer.recent() if e.phase == "attn.tiles"]
+    assert len(events) == 2
+    for e in events:
+        steps = 16 * (e["lqp"] // e["bq"]) * (e["lkp"] // e["bkv"])
+        assert e["lkp"] > {384: 300, 11264: 8 * 1374}[e["lkp"]]  # padded
+        assert e["stage2_masked_steps"] == (steps if causal else 0)
+    s8 = events[1]
+    assert (s8["bq"], s8["bkv"]) == (512, 2816)
+    # the key scale row of 2,816 entries every step, and the q scale, M
+    # and Σ rows of 512 once per 4 steps
+    assert s8["scale_bytes_per_step"] == 4 * (2816 + 3 * 512 // 4) < 100_000
